@@ -12,8 +12,9 @@ quantity: counts, MB, speedups, ...). Sections:
   blockmm  — batched block MM (slot-indexed fused pipelines over all
              ciphertext tiles) vs the sequential tile loop
   dist     — schedule="sharded" (limb-sharded shard_map MO-HLT driving the
-             fused Pallas kernel per rank) across forced host-device counts
-             (subprocesses set XLA_FLAGS): fused vs "sharded_xla" wall
+             fused Pallas kernel per rank) across device counts (forced
+             host devices in subprocesses under JAX_PLATFORMS=cpu, the
+             process's own devices otherwise): fused vs "sharded_xla" wall
              times, measured-vs-predicted collective bytes, and in-program
              hoist bytes before/after the ct-slot dedup
   serve    — multi-tenant secure serving: cross-request batched (one launch
@@ -110,11 +111,8 @@ def _t(fn, *args, reps=3, **kw):
 
 
 def _block(x):
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
+    import jax
+    jax.block_until_ready(x)
 
 
 def row(name, us, derived):
@@ -290,73 +288,78 @@ def bench_blockmm(smoke: bool = False):
     }
 
 
-# child script for bench_dist: XLA_FLAGS must be set BEFORE jax initializes,
-# so every device count runs in a fresh subprocess.  Prepended with
-# "DEV=..; LOGN=..; REPS=..; BATCH=.." by the parent.
-_DIST_CHILD = """
-import json, time
-import numpy as np
-import repro
-import jax
-from repro.core.ckks import CkksEngine
-from repro.core.compile import HEContext, compile_hlt
-from repro.core.hemm import plan_hemm, encrypt_matrix
-from repro.core.params import toy_params
-from repro.launch.mesh import make_mesh_for
-from repro.distributed.hlo_analysis import collective_stats
+def _dist_case(dev: int, logn: int, reps: int, batch: int) -> dict:
+    """One device count of bench_dist, on the first ``dev`` devices."""
+    import jax
+    from repro.core.ckks import CkksEngine
+    from repro.core.compile import HEContext, compile_hlt
+    from repro.core.hemm import plan_hemm, encrypt_matrix
+    from repro.core.params import toy_params
+    from repro.launch.mesh import make_mesh_for
+    from repro.distributed.hlo_analysis import collective_stats
 
-params = toy_params(logN=LOGN, L=4, k=3, beta=2)
-mesh = make_mesh_for(DEV, model_parallel=DEV) if DEV > 1 else None
-ctx = HEContext(CkksEngine(params), mesh=mesh)
-rng = np.random.default_rng(0)
-plan = plan_hemm(ctx.eng, 4, 3, 5)
-ctx.keygen(rng, rot_steps=plan.rot_steps)
-cts = [encrypt_matrix(ctx.eng, ctx.keys, rng.uniform(-1, 1, (4, 3)), rng)
-       for _ in range(BATCH)]
+    params = toy_params(logN=logn, L=4, k=3, beta=2)
+    mesh = make_mesh_for(dev, model_parallel=dev) if dev > 1 else None
+    ctx = HEContext(CkksEngine(params), mesh=mesh)
+    rng = np.random.default_rng(0)
+    plan = plan_hemm(ctx.eng, 4, 3, 5)
+    ctx.keygen(rng, rot_steps=plan.rot_steps)
+    cts = [encrypt_matrix(ctx.eng, ctx.keys, rng.uniform(-1, 1, (4, 3)), rng)
+           for _ in range(batch)]
 
-
-def timed(fn):
-    out = fn()                               # warmup / compile
-    jax.block_until_ready([c.c0 for c in out])
-    best = float("inf")
-    for _ in range(REPS):
-        t0 = time.perf_counter()
-        out = fn()
+    def timed(fn):
+        out = fn()                               # warmup / compile
         jax.block_until_ready([c.c0 for c in out])
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e6
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            out = fn()
+            jax.block_until_ready([c.c0 for c in out])
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e6
+
+    level = cts[0].level
+    run = compile_hlt(ctx, [plan.ds_sigma] * batch, level=level,
+                      schedule="sharded")
+    runx = compile_hlt(ctx, [plan.ds_sigma] * batch, level=level,
+                       schedule="sharded_xla")
+    st = collective_stats(run.hlo(cts))
+    # hoist-dedup story: the hemm Step-2 aliasing pattern (2 unique inputs
+    # across the batch) — bytes before/after the ct-slot dedup, from the plans
+    hint = tuple(b % 2 for b in range(batch))
+    aliased = compile_hlt(ctx, [plan.ds_sigma] * batch, level=level,
+                          schedule="sharded", ct_slots=hint)
+    res = dict(devices=dev, n_model=ctx.n_model, n_ct=ctx.n_ct,
+               sharded_us=round(timed(lambda: run(cts)), 1),
+               sharded_xla_us=round(timed(lambda: runx(cts)), 1),
+               predicted_collective_bytes=run.plan.collective_bytes,
+               measured_collective_bytes=st.total_bytes,
+               collective_count=st.count,
+               hoist_bytes_dedup=aliased.plan.hoist_bytes,
+               hoist_bytes_naive=aliased.plan.hoist_bytes_naive,
+               n_ct_slots=aliased.plan.n_ct_slots)
+    if dev == 1:
+        mo = compile_hlt(ctx, [plan.ds_sigma] * batch, level=level,
+                         schedule="mo")
+        res["mo_us"] = round(timed(lambda: mo(cts)), 1)
+    return res
 
 
-run = compile_hlt(ctx, [plan.ds_sigma] * BATCH, level=cts[0].level,
-                  schedule="sharded")
-runx = compile_hlt(ctx, [plan.ds_sigma] * BATCH, level=cts[0].level,
-                   schedule="sharded_xla")
-st = collective_stats(run.sharded_hlo(cts))
-# hoist-dedup story: the hemm Step-2 aliasing pattern (2 unique inputs
-# across the batch) — bytes before/after the ct-slot dedup, from the plans
-hint = tuple(b % 2 for b in range(BATCH))
-aliased = compile_hlt(ctx, [plan.ds_sigma] * BATCH, level=cts[0].level,
-                      schedule="sharded", ct_slots=hint)
-res = dict(devices=DEV, n_model=ctx.n_model, n_ct=ctx.n_ct,
-           sharded_us=round(timed(lambda: run(cts)), 1),
-           sharded_xla_us=round(timed(lambda: runx(cts)), 1),
-           predicted_collective_bytes=run.plan.collective_bytes,
-           measured_collective_bytes=st.total_bytes,
-           collective_count=st.count,
-           hoist_bytes_dedup=aliased.plan.hoist_bytes,
-           hoist_bytes_naive=aliased.plan.hoist_bytes_naive,
-           n_ct_slots=aliased.plan.n_ct_slots)
-if DEV == 1:
-    mo = compile_hlt(ctx, [plan.ds_sigma] * BATCH, level=cts[0].level,
-                     schedule="mo")
-    res["mo_us"] = round(timed(lambda: mo(cts)), 1)
-print(json.dumps(res))
+# forced-CPU child for bench_dist: XLA_FLAGS must be set BEFORE jax
+# initializes, so on the CPU every device count runs in a fresh process.
+_DIST_CHILD = """
+import json, sys
+sys.path.insert(0, {bench!r})
+import repro
+import run
+print(json.dumps(run._dist_case({dev}, {logn}, {reps}, {batch})))
 """
 
 
 def bench_dist(smoke: bool = False):
     """schedule="sharded" (limb-sharded shard_map MO-HLT through the FUSED
-    Pallas datapath, core/hlt_dist.py) across forced host-device counts:
+    Pallas datapath, core/hlt_dist.py) across device counts (forced host
+    devices under JAX_PLATFORMS=cpu, the real devices otherwise):
     per-count wall time of one batched HLT for the fused datapath vs the
     "sharded_xla" pre-fusion baseline, the plan's PREDICTED collective bytes
     vs the bytes MEASURED in the compiled HLO
@@ -370,19 +373,31 @@ def bench_dist(smoke: bool = False):
     signal.)"""
     counts = (1, 4) if smoke else (1, 2, 4)
     reps = 1 if smoke else 3
-    batch = 4
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+    batch, logn = 4, 6
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(here, "..", "src")
+    # Forced host devices only where JAX is held to the CPU. Anywhere else
+    # the device counts run in this process on jax.devices(): a chip belongs
+    # to one process, so a child started after this one touched JAX could
+    # not reach it.
+    forced_cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
+    if not forced_cpu:
+        import jax
+        counts = tuple(c for c in counts if c <= len(jax.devices()))
     per_count = {}
     for dev in counts:
-        code = (f"DEV={dev}; LOGN=6; REPS={reps}; BATCH={batch}\n"
-                + _DIST_CHILD)
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={dev}")
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True, timeout=1800)
-        assert r.returncode == 0, r.stderr[-3000:]
-        res = json.loads(r.stdout.strip().splitlines()[-1])
+        if forced_cpu:
+            code = _DIST_CHILD.format(bench=here, dev=dev, logn=logn,
+                                      reps=reps, batch=batch)
+            env = dict(os.environ, XLA_FLAGS=(
+                f"--xla_force_host_platform_device_count={dev}"))
+            env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+            r = subprocess.run([sys.executable, "-c", code], env=env,
+                               capture_output=True, text=True, timeout=1800)
+            assert r.returncode == 0, r.stderr[-3000:]
+            res = json.loads(r.stdout.strip().splitlines()[-1])
+        else:
+            res = _dist_case(dev, logn, reps, batch)
         per_count[str(dev)] = res
         row(f"dist/devices={dev}/sharded_hlt", res["sharded_us"],
             f"coll_pred_B={res['predicted_collective_bytes']};"
@@ -397,7 +412,7 @@ def bench_dist(smoke: bool = False):
         if "mo_us" in res:
             row(f"dist/devices={dev}/mo_hlt", res["mo_us"],
                 "single-device reference")
-    RESULTS["dist"] = {"batch": batch, "logN": 6, "per_device_count":
+    RESULTS["dist"] = {"batch": batch, "logN": logn, "per_device_count":
                        per_count}
 
 
@@ -585,6 +600,8 @@ def main() -> None:
     import inspect
 
     import repro  # noqa: F401
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("section", nargs="?", default=None,
                     help="run only sections whose name contains this")

@@ -16,7 +16,7 @@ previously only asserted in tests:
 * JX004 — full stage coverage: when the plan's ``datapath`` is "pallas"
   (the fused hoist/ModDown stages, DESIGN.md §7), NO XLA-lowered NTT/iNTT
   remains in the traced program.  The XLA transforms are named-jit wrappers
-  (core/ntt.py ``NTT_EQN_NAMES``) so they census as pjit eqns; the Pallas
+  (core/ntt.py ``NTT_EQN_NAMES``) so they census as ``jit`` eqns; the Pallas
   kernels call the unjitted ``*_raw`` recursions and contribute none.
 
 Sharded programs lint their shard_map pipeline; single-device ``pallas``
@@ -33,10 +33,10 @@ from repro.distributed import hlo_analysis
 
 
 def _named_ntt_count(jaxpr) -> int:
-    """XLA-lowered NTT/iNTT eqns (named-jit pjit markers) in a jaxpr."""
+    """XLA-lowered NTT/iNTT eqns (named-jit ``jit`` markers) in a jaxpr."""
     n = 0
     for eqn in hlo_analysis.iter_jaxpr_eqns(jaxpr):
-        if (eqn.primitive.name == "pjit"
+        if (eqn.primitive.name == "jit"
                 and str(eqn.params.get("name")) in NTT_EQN_NAMES):
             n += 1
     return n

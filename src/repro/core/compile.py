@@ -512,7 +512,9 @@ def compile_hlt(ctx: HEContext, diags: Union[DiagSet, Sequence[DiagSet]], *,
             def _build_tabs():
                 t = hlt_dist.build_shard_tables(eng.params, level,
                                                 ctx.n_model)
-                return (t, hlt_dist.shard_operand_arrays(t))
+                arrays, _ = hlt_dist.place_operands(
+                    t, ctx.rules, hlt_dist.shard_operand_arrays(t), ())
+                return (t, arrays)
             _, sharded_tabs = ctx.arena.slot(
                 "sharded_tables", eng, (level, ctx.n_model), _build_tabs)
             m_pad = sharded_tabs[0].M_pad
@@ -525,7 +527,8 @@ def compile_hlt(ctx: HEContext, diags: Union[DiagSet, Sequence[DiagSet]], *,
                                            (0, 0)))
                 stacked[2] = jnp.pad(rk1, ((0, 0), (0, 0), (0, 0), (0, pad),
                                            (0, 0)))
-            operands = tuple(stacked)
+            _, operands = hlt_dist.place_operands(
+                sharded_tabs[0], ctx.rules, {}, tuple(stacked))
             # batch-index -> slot tables, padded to the ct-axis multiple,
             # arena-owned like every other operand (hlt_dist.build_slot_tables)
             b_pad = -(-ctb // max(1, ctx.n_ct)) * max(1, ctx.n_ct)
@@ -597,6 +600,19 @@ class CompiledHLT:
         self._diag_slots = (None if plan.batch is None else
                             jnp.asarray(np.array(plan.diag_slots, np.int32)))
         self._gen = ctx._generation
+
+    def device_bytes(self) -> dict:
+        """Bytes of this program's compile-time operands (rotation keys,
+        diagonals, sharded tables) resident on each device, by device id."""
+        leaves = jax.tree_util.tree_leaves(
+            (self._operands, None if self._sharded is None
+             else self._sharded[1]))
+        held: dict = {}
+        for arr in leaves:
+            for shard in arr.addressable_shards:
+                held[shard.device.id] = (held.get(shard.device.id, 0)
+                                         + shard.data.nbytes)
+        return held
 
     # -- helpers -------------------------------------------------------------
 
@@ -751,30 +767,42 @@ class CompiledHLT:
         return [self._finish(out0[b, :lvl], out1[b, :lvl], it.scale, ds)
                 for b, (it, ds) in enumerate(zip(items, self._diags, strict=True))]
 
-    def sharded_hlo(self, items) -> str:
-        """Optimized HLO text of the sharded SPMD program for this batch —
-        benchmarks feed it to distributed/hlo_analysis.collective_stats to
-        MEASURE collective bytes against the plan's prediction."""
-        assert self.plan.schedule.startswith("sharded"), self.plan.schedule
+    def hlo(self, items) -> str:
+        """Optimized HLO text of the batched program this call would run
+        (``pallas``: the slot-indexed fused pipeline; ``sharded``: the SPMD
+        program) — benchmarks feed the sharded text to
+        distributed/hlo_analysis.collective_stats to MEASURE collective bytes
+        against the plan's prediction, and the chip smoke test checks that
+        the fused kernel (``tpu_custom_call``) is in it."""
         self.ctx._check_generation(self._gen)
-        tabs, _ = self._sharded
-        args, layout = self._sharded_args(items)
-        fn = self.ctx._sharded_pipeline(tabs, self.plan.d_pad,
-                                        self.plan.nbeta, self._datapath,
-                                        self.plan.chunk, layout,
-                                        self.plan.datapath)
-        return fn.lower(args).compile().as_text()
+        if self.plan.schedule.startswith("sharded"):
+            tabs, _ = self._sharded
+            args, layout = self._sharded_args(items)
+            fn = self.ctx._sharded_pipeline(tabs, self.plan.d_pad,
+                                            self.plan.nbeta, self._datapath,
+                                            self.plan.chunk, layout,
+                                            self.plan.datapath)
+            return fn.lower(args).compile().as_text()
+        assert self.plan.schedule == "pallas" and self.plan.batch is not None
+        fn, args, _, _ = self._batched_pallas_args(items)
+        return fn.lower(*args).compile().as_text()
 
-    def _run_batched_pallas(self, items) -> list:
+    def _batched_pallas_args(self, items):
+        """(pipeline, args, unique hoisting products, ct slots) of one
+        batched fused-schedule call."""
         ctx, plan = self.ctx, self.plan
         hoisted, ct_slots = self._hoist_items(items)
         digits = jnp.stack([h.digits for h in hoisted])
         c0e = jnp.stack([h.c0_ext for h in hoisted])
         c1e = jnp.stack([h.c1_ext for h in hoisted])
         fn = ctx._pallas_pipeline(plan.level, plan.chunk, "indexed")
-        c0b, c1b = fn(digits, c0e, c1e, *self._operands,
-                      jnp.asarray(np.array(ct_slots, np.int32)),
-                      self._diag_slots)
+        args = (digits, c0e, c1e, *self._operands,
+                jnp.asarray(np.array(ct_slots, np.int32)), self._diag_slots)
+        return fn, args, hoisted, ct_slots
+
+    def _run_batched_pallas(self, items) -> list:
+        fn, args, hoisted, ct_slots = self._batched_pallas_args(items)
+        c0b, c1b = fn(*args)
         return [self._finish(c0b[b], c1b[b], hoisted[ct_slots[b]].scale, ds)
                 for b, ds in enumerate(self._diags)]
 
@@ -865,16 +893,7 @@ class HEMMProgram:
         eng, keys, p = self.ctx.eng, self.ctx.keys, self.mm_plan
         assert ctA.level == ctB.level == self.plan.level
         if self.plan.batched:
-            ctA0, ctB0 = self._step1([ctA, ctB])
-            if self.plan.schedule.startswith("sharded"):
-                # the SPMD program hoists internally (limb-local, off the
-                # replicated inputs; the fused datapath hoists each unique
-                # ciphertext ONCE per rank) — feed the Step-1 cts directly
-                outs = self._step2([ctA0] * p.l + [ctB0] * p.l)
-            else:
-                hstA, hstB = hoist_batched(
-                    eng, [ctA0, ctB0], datapath=self.plan.step2.datapath)
-                outs = self._step2([hstA] * p.l + [hstB] * p.l)
+            outs = self._step2(self._step2_items(ctA, ctB))
         else:
             s1a, s1b = self._step1
             ctA0, ctB0 = s1a(ctA), s1b(ctB)
@@ -892,6 +911,35 @@ class HEMMProgram:
             prod = eng.rescale(eng.mult(outs[k], outs[p.l + k], keys))
             acc = prod if acc is None else eng.add(acc, prod)
         return acc
+
+    def device_bytes(self) -> dict:
+        """Compile-time operand bytes per device id, both HLT steps."""
+        held: dict = {}
+        steps = (self._step1, self._step2) if self.plan.batched else \
+            tuple(self._step1) + tuple(self._step2)
+        for step in steps:
+            for dev, n in step.device_bytes().items():
+                held[dev] = held.get(dev, 0) + n
+        return held
+
+    def _step2_items(self, ctA: Ciphertext, ctB: Ciphertext) -> list:
+        """Batched Step 1, then the 2·l Step-2 batch items."""
+        l = self.mm_plan.l
+        ctA0, ctB0 = self._step1([ctA, ctB])
+        if self.plan.schedule.startswith("sharded"):
+            # the SPMD program hoists internally (limb-local, off the
+            # replicated inputs; the fused datapath hoists each unique
+            # ciphertext ONCE per rank) — feed the Step-1 cts directly
+            return [ctA0] * l + [ctB0] * l
+        hstA, hstB = hoist_batched(self.ctx.eng, [ctA0, ctB0],
+                                   datapath=self.plan.step2.datapath)
+        return [hstA] * l + [hstB] * l
+
+    def hlo(self, ctA: Ciphertext, ctB: Ciphertext) -> str:
+        """Optimized HLO text of the batched Step-2 launch (the 2·l HLTs)."""
+        assert self.plan.batched, "only batched programs have one Step-2 launch"
+        self.ctx._check_generation(self._gen)
+        return self._step2.hlo(self._step2_items(ctA, ctB))
 
 
 def compile_hemm(ctx: HEContext, plan, *, level: Optional[int] = None,

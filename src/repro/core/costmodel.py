@@ -58,10 +58,10 @@ def pick_rotation_chunk(params: "HEParams", nbeta: int | None = None,
     """Largest rotation chunk whose fused-HLT per-grid-step working set
     (kernels/fused_hlt.py docstring) fits the per-core VMEM budget.
 
-    Per grid step the kernel keeps resident β digit rows + c0e/c1e + the two
-    accumulator rows, and streams per rotation: one diagonal row, one perm
-    table row (i32 — same bytes as a u32 limb row) and 2β rot-key rows.
-    Each row is N u32 coefficients (4 bytes).
+    The per-step row count is ``kernels/fused_hlt.working_set_rows`` (P·c0,
+    P·c1 and two accumulator rows resident; per rotation β rotated digit
+    rows, a rotated P·c0 row, one diagonal row and 2β rot-key rows; all
+    double-buffered). Each row is N u32 coefficients (4 bytes).
     """
     nbeta = params.beta if nbeta is None else nbeta
     headroom = VMEM_HEADROOM if headroom is None else headroom
@@ -93,8 +93,9 @@ def fused_stage_working_sets(params: "HEParams", *, nbeta: int, chunk: int,
     row = 4 * params.N
     return {
         "rot": int(working_set_rows(nbeta, chunk) * row),
-        "hoist": int(hoist_working_set_rows(nbeta, alpha) * row),
-        "moddown": int(moddown_working_set_rows(params.k + 1) * row),
+        "hoist": int(hoist_working_set_rows(nbeta, alpha, params.logN) * row),
+        "moddown": int(moddown_working_set_rows(params.k + 1, params.logN)
+                       * row),
     }
 
 
@@ -160,11 +161,11 @@ def select_schedule(params: "HEParams", nbeta: int | None = None,
     """Cost-model schedule pick for compile_hlt/compile_hemm (schedule=None).
 
     Single device — the fused Pallas datapath needs its minimal per-grid-step
-    working set (the chunk=1 residency of pick_rotation_chunk's formula: β
-    digit rows, c0e/c1e, two accumulator rows, plus one rotation's operands)
-    to fit the per-core VMEM budget.  When it does (every shipped parameter
-    set), the fused kernel is the schedule; when a hypothetical parameter set
-    overflows even chunk=1, fall back to the u64 limb-outer reference ("mo").
+    working set (``working_set_rows(nbeta, 1)``, the chunk=1 residency of
+    pick_rotation_chunk's formula) to fit the per-core VMEM budget.  When it
+    does (every shipped parameter set), the fused kernel is the schedule;
+    when a hypothetical parameter set overflows even chunk=1, fall back to
+    the u64 limb-outer reference ("mo").
 
     Multi-device mesh (``n_model``-way limb sharding × ``n_ct``-way
     ciphertext-batch sharding, from HEContext's mesh) — compare PER-DEVICE
@@ -196,8 +197,8 @@ def select_schedule(params: "HEParams", nbeta: int | None = None,
     """
     nbeta = params.beta if nbeta is None else nbeta
     headroom = VMEM_HEADROOM if headroom is None else headroom
-    row = 4.0 * params.N
-    min_working_set = (nbeta + 4 + 2 * nbeta + 2) * row
+    from repro.kernels.fused_hlt import working_set_rows
+    min_working_set = working_set_rows(nbeta, 1) * 4.0 * params.N
     single = "pallas" if min_working_set <= headroom * vmem_bytes else "mo"
     n_model, n_ct = max(1, n_model), max(1, n_ct)
     if n_model * n_ct <= 1 or single != "pallas":
@@ -264,6 +265,7 @@ def select_chain_schedules(params: "HEParams", hops, *,
     flip saves.  With one device, or a single hop, the result degenerates
     to per-hop ``select_schedule`` picks.
     """
+    from repro.kernels.fused_hlt import working_set_rows
     headroom = VMEM_HEADROOM if headroom is None else headroom
     n_model, n_ct = max(1, n_model), max(1, n_ct)
     row = 4.0 * params.N
@@ -273,7 +275,7 @@ def select_chain_schedules(params: "HEParams", hops, *,
     singles, costs = [], []
     for hop in hops:
         nbeta = hop.get("nbeta") or params.beta
-        min_ws = (nbeta + 4 + 2 * nbeta + 2) * row
+        min_ws = working_set_rows(nbeta, 1) * row
         sname = "pallas" if min_ws <= headroom * vmem_bytes else "mo"
         singles.append(sname)
         single_dev, shard_dev = _hlt_device_costs(

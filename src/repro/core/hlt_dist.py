@@ -453,7 +453,8 @@ _STACKED_TAB_KEYS = ("w_stack", "d_stack", "mask_stack")
 
 
 def _tab_keys(tabs: ShardTables) -> list:
-    return (["q32", "qneg", "psi_m", "psii_m", "ninv_m", "p_raise_m",
+    return (["q32", "qneg", "psi_m", "psii_m", "psi_tw", "psii_tw", "ninv_m",
+             "p_raise_m",
              "md_hat_inv", "md_W", "md_D", "md_p_inv", "sel_drop"]
             + list(_STACKED_TAB_KEYS)
             + [f"{pre}{j}" for j in range(len(tabs.digits))
@@ -473,6 +474,8 @@ def shard_operand_arrays(tabs: ShardTables) -> dict:
     alpha = max(dg["W_full"].shape[1] for dg in tabs.digits)
     out = dict(
         q32=tabs.q32, qneg=tabs.qneg, psi_m=tabs.psi_m, psii_m=tabs.psii_m,
+        psi_tw=ntt.expand_twiddles(tabs.psi_m),
+        psii_tw=ntt.expand_twiddles(tabs.psii_m, inverse=True),
         ninv_m=tabs.ninv_m, p_raise_m=tabs.p_raise_m,
         md_hat_inv=tabs.md["hat_inv_full"], md_W=tabs.md["W_full"],
         md_D=tabs.md["D_full"], md_p_inv=tabs.md["p_inv_full"],
@@ -535,6 +538,41 @@ def expected_collectives(tabs: ShardTables) -> dict:
     body is then emitted without shard_map/psum), and never any other
     collective primitive."""
     return {"psum": 2 if tabs.n_model > 1 else 0}
+
+
+def _operand_specs(tabs: ShardTables, limb) -> tuple:
+    """PartitionSpecs of the compile-time operands: (table dict, rotation
+    operand dict), limb rows over ``limb`` (None = replicated)."""
+    from jax.sharding import PartitionSpec as P
+    tab_specs = {k: (P(None, limb)
+                     if k == "sel_drop" or k in _STACKED_TAB_KEYS
+                     else P(limb, None))
+                 for k in _tab_keys(tabs)}
+    op_specs = dict(
+        u=P(None, None, limb, None),
+        rk0=P(None, None, None, limb, None),
+        rk1=P(None, None, None, limb, None),
+        perms=P(None, None, None), is_id=P(None, None, None))
+    return tab_specs, op_specs
+
+
+def place_operands(tabs: ShardTables, rules, tab_arrays: dict,
+                   operands: tuple) -> tuple:
+    """Put the table arrays and the stacked (u, rk0, rk1, perms, is_id)
+    rotation operands on the mesh with the shardings the SPMD program reads
+    them with, so each device holds its limb rows once instead of the
+    program resharding from one device on every call."""
+    from jax.sharding import NamedSharding
+    mesh = rules.mesh
+    if mesh is None:
+        return tab_arrays, operands
+    limb_axes = _physical_axes(rules, "limbs") if tabs.n_model > 1 else ()
+    tab_specs, op_specs = _operand_specs(tabs, limb_axes or None)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
+    tabs_out = {k: put(v, tab_specs[k]) for k, v in tab_arrays.items()}
+    ops_out = tuple(put(x, op_specs[k]) for k, x in zip(
+        ("u", "rk0", "rk1", "perms", "is_id"), operands)) if operands else ()
+    return tabs_out, ops_out
 
 
 def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
@@ -609,7 +647,6 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
     exact (no float reordering) and bit-identical to the single-device MO
     schedule.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert datapath in ("pallas", "xla"), datapath
@@ -668,7 +705,7 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
             # contributor per row -> the sum is exact (collective volume is
             # the paper's BaseConv traffic, nothing else crosses ranks)
             part = jnp.sum(t["sel_drop"][None, :, :, None] * y[:, None],
-                           axis=2)                       # (B, |drop|, N)
+                           axis=2, dtype=jnp.uint32)     # (B, |drop|, N)
             y_drop = (jax.lax.psum(part, limb_axes) if limb_axes else part)
             conv = baseconv_rows(y_drop, t["md_W"], t["md_D"], md_invd, q, qn)
             conv_eval = ntt.ntt_mont(conv, t["psi_m"], q, qn)
@@ -705,7 +742,8 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
             h_qneg[rows] = np.asarray(tabs.qneg_main)[s_:e_]
             h_hat[rows] = np.asarray(tabs.digits[j]["hat_inv_m"])
             h_invd[j, :na] = tabs.digits[j]["inv_d"]
-        h_psii, h_ninv, h_hat = map(jnp.asarray, (h_psii, h_ninv, h_hat))
+        h_psii = jnp.asarray(ntt.expand_twiddles(h_psii, inverse=True))
+        h_ninv, h_hat = jnp.asarray(h_ninv), jnp.asarray(h_hat)
         h_q, h_qneg = jnp.asarray(h_q), jnp.asarray(h_qneg)
         h_invd = jnp.asarray(h_invd.astype(fp_dtype))
 
@@ -717,7 +755,7 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
             y = basechange.intt_scale(x_dig, h_psii, h_ninv, h_hat, h_q,
                                       h_qneg, interpret=interp)
             return basechange.baseconv_ntt(
-                y, t["w_stack"], t["d_stack"], h_invd, t["psi_m"], q, qn,
+                y, t["w_stack"], t["d_stack"], h_invd, t["psi_tw"], q, qn,
                 c1f_i, t["mask_stack"], interpret=interp)
         return jax.vmap(one)(c1rep, c1f)
 
@@ -726,13 +764,13 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
         (STILL the only collective) stay on XLA between the two kernels."""
         def mod_down(acc):
             y = jax.vmap(lambda x: basechange.intt_scale(
-                x, t["psii_m"], t["ninv_m"], t["md_hat_inv"], q, qn,
+                x, t["psii_tw"], t["ninv_m"], t["md_hat_inv"], q, qn,
                 interpret=interp))(acc)
             part = jnp.sum(t["sel_drop"][None, :, :, None] * y[:, None],
-                           axis=2)                       # (B, |drop|, N)
+                           axis=2, dtype=jnp.uint32)     # (B, |drop|, N)
             y_drop = (jax.lax.psum(part, limb_axes) if limb_axes else part)
             return jax.vmap(lambda x, yd: basechange.moddown_finish(
-                x, yd, t["md_W"], t["md_D"], md_invd, t["psi_m"],
+                x, yd, t["md_W"], t["md_D"], md_invd, t["psi_tw"],
                 t["md_p_inv"], q, qn, interpret=interp))(acc, y_drop)
         return mod_down
 
@@ -797,15 +835,7 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
         mod_down = make_mod_down(t, q, qn)
         return mod_down(acc0), mod_down(acc1)
 
-    tab_specs = {k: (P(None, limb)
-                     if k == "sel_drop" or k in _STACKED_TAB_KEYS
-                     else P(limb, None))
-                 for k in _tab_keys(tabs)}
-    op_specs = dict(
-        u=P(None, None, limb, None),
-        rk0=P(None, None, None, limb, None),
-        rk1=P(None, None, None, limb, None),
-        perms=P(None, None, None), is_id=P(None, None, None))
+    tab_specs, op_specs = _operand_specs(tabs, limb)
     if datapath == "pallas":
         assert hoist_layout in ("dedup", "element"), hoist_layout
         body = body_pallas
@@ -824,8 +854,8 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
     out_specs = (P(ct, limb, None),) * 2
     if mesh is None:
         return body
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def lower_mo_hlt_spmd(params: HEParams, mesh, rules, d: int = 127,

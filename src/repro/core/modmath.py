@@ -115,8 +115,11 @@ def montsum(x, q32, axis: int = 0):
         h = n // 2
         a = lax.slice_in_dim(x, 0, h, axis=axis)
         b = lax.slice_in_dim(x, h, 2 * h, axis=axis)
-        rest = lax.slice_in_dim(x, 2 * h, n, axis=axis)
-        x = jnp.concatenate([montadd(a, b, q32), rest], axis=axis)
+        s = montadd(a, b, q32)
+        if n > 2 * h:           # odd count: carry the last term (Mosaic has
+            s = jnp.concatenate(  # no zero-sized slices, so only when present)
+                [s, lax.slice_in_dim(x, 2 * h, n, axis=axis)], axis=axis)
+        x = s
         n = n - h
     return jnp.squeeze(x, axis=axis)
 

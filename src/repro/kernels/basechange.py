@@ -8,17 +8,19 @@ computation — a per-row inverse transform, a small limb-axis matmul
 kernels here:
 
 * ``intt_scale`` — grid over rows: one resident iNTT pass (all log2(N)
-  butterfly stages from core/ntt.py's raw recursion) followed by a
+  butterfly stages, core/ntt.py ``intt_tile``) followed by a
   montmul with a per-row scale (``q̂_i⁻¹`` for the hoist digits, the
   ModDown drop-basis ``q̂_i⁻¹`` otherwise).
 * ``baseconv_ntt`` / ``moddown_finish`` — grid over *target* rows: the
-  HPS BaseConv as a vectorized limb-axis MAC (tree reduction, f32/f64
-  floor-correction in-tile), then one resident forward-NTT pass, then
+  HPS BaseConv as a MAC over the source rows (f32/f64 floor-correction
+  in-tile), then one resident forward-NTT pass, then
   either the hoist's own-row passthrough select or ModDown's
   ``(x - conv)·P⁻¹``.
 
-Everything stays on the u32 Montgomery datapath and is bit-exact vs the
-u64 reference schedules (tests/test_fused_datapath.py). Table layouts are
+Everything stays on the u32 Montgomery datapath; with f64 correction
+tables (the CPU) it is bit-exact vs the u64 reference schedules
+(tests/test_fused_datapath.py), with the chip's f32 tables the overflow
+count may be off by one near an integer (DESIGN.md §7). Table layouts are
 digit-padded to ``alpha = max |digit|`` rows so BlockSpec indexing stays
 static: padded rows carry zero ``hat_inv``/``inv_d``/``W`` and contribute
 exactly zero.
@@ -39,6 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import modmath as mm
 from repro.core import ntt as core_ntt
+from repro.kernels import common
 
 #: floor-correction epsilon of the HPS BaseConv — matches the sharded
 #: datapath (core/hlt_dist.py); bit-equal to the u64 reference's f64+1e-9
@@ -47,121 +50,142 @@ CORRECTION_EPS = 0.5e-6
 
 
 # ---------------------------------------------------------------------------
-# row-wise kernels
+# row-wise kernels (one (R, C) coefficient tile per row, kernels/common.py)
 # ---------------------------------------------------------------------------
 
 
-def _intt_scale_kernel(x_ref, psii_ref, ninv_ref, scale_ref, q_ref, qneg_ref,
+def _conv_tile(ys, invd, w, dmod, q, qn):
+    """HPS BaseConv of one target row: ys are the source rows' scaled
+    coefficient tiles, invd/w their per-row float 1/q_i and Montgomery
+    weights (scalars), dmod the target's D mod q (Montgomery)."""
+    fsum = None
+    for y, c in zip(ys, invd, strict=True):
+        term = common.i32_to_float(y, c.dtype) * c
+        fsum = term if fsum is None else fsum + term
+    v = common.float_to_u32(jnp.floor(fsum + CORRECTION_EPS))
+    acc = None
+    for y, wa in zip(ys, w, strict=True):
+        term = mm.montmul(y, wa, q, qn)
+        acc = term if acc is None else mm.montadd(acc, term, q)
+    return mm.montsub(acc, mm.montmul(v, dmod, q, qn), q)
+
+
+def _intt_scale_kernel(x_ref, tw_ref, ninv_ref, scale_ref, q_ref, qneg_ref,
                        o_ref):
-    q, qn = q_ref[0, 0], qneg_ref[0, 0]
-    coeff = core_ntt.intt_mont_raw(x_ref[0, :], psii_ref[0, :],
-                                   ninv_ref[0, 0], q, qn)
-    o_ref[0, :] = mm.montmul(coeff, scale_ref[0, 0], q, qn)
+    r = pl.program_id(0)
+    q, qn = q_ref[r, 0], qneg_ref[r, 0]
+    coeff = core_ntt.intt_tile(x_ref[...], tw_ref[...], ninv_ref[r, 0], q, qn)
+    o_ref[...] = mm.montmul(coeff, scale_ref[r, 0], q, qn)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def intt_scale(x, psii_m, ninv_m, scale_m, q32, qneg, *,
-               interpret: bool = True):
+def intt_scale(x, psii_tw, ninv_m, scale_m, q32, qneg, *, interpret: bool):
     """Per-row iNTT + montmul by a per-row Montgomery scale.
 
-    x: (R, N) eval-domain u32; psii_m: (R, N); ninv_m/scale_m/q32/qneg:
-    (R, 1). Returns (R, N) coeff-domain, scaled."""
+    x: (R, N) eval-domain u32; psii_tw: (R, log2 N, Rt, C) inverse twiddle
+    tiles (core/ntt.expand_twiddles); ninv_m/scale_m/q32/qneg: (R, 1).
+    Returns (R, N) coeff-domain, scaled."""
     R, N = x.shape
-    row = pl.BlockSpec((1, N), lambda r: (r, 0))
-    col = pl.BlockSpec((1, 1), lambda r: (r, 0))
-    return pl.pallas_call(
-        _intt_scale_kernel,
-        grid=(R,),
-        in_specs=[row, row, col, col, col, col],
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((R, N), jnp.uint32),
-        interpret=interpret,
-    )(x, psii_m, ninv_m, scale_m, q32, qneg)
+    Rt, C = core_ntt.tile_shape(N)
+    row = pl.BlockSpec((None, Rt, C), lambda r: (r, 0, 0))
+    tw = pl.BlockSpec((None,) + psii_tw.shape[1:], lambda r: (r, 0, 0, 0))
+    with common.lowering_scope(interpret):
+        out = pl.pallas_call(
+            _intt_scale_kernel,
+            grid=(R,),
+            in_specs=[row, tw] + [common.SMEM] * 4,
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct((R, Rt, C), jnp.uint32),
+            interpret=interpret,
+        )(common.tiles(x), psii_tw, ninv_m, scale_m, q32, qneg)
+    return common.untiles(out)
 
 
-def _baseconv_ntt_kernel(y_ref, w_ref, d_ref, invd_ref, psi_ref, q_ref,
+def _baseconv_ntt_kernel(y_ref, w_ref, d_ref, invd_ref, tw_ref, q_ref,
                          qneg_ref, pt_ref, mask_ref, o_ref):
-    y = y_ref[...]                                  # (alpha, N) digit rows
-    q, qn = q_ref[0, 0], qneg_ref[0, 0]
-    invd = invd_ref[0, :, :]                        # (alpha, 1) fp
-    v = jnp.floor(jnp.sum(y.astype(invd.dtype) * invd, axis=0)
-                  + CORRECTION_EPS).astype(jnp.uint32)          # (N,)
-    prod = mm.montmul(y, w_ref[0, 0, :][:, None], q, qn)        # (alpha, N)
-    acc = mm.montsum(prod, q, axis=0)
-    corr = mm.montmul(v, d_ref[0, 0, 0], q, qn)
-    conv = mm.montsub(acc, corr, q)
-    res = core_ntt.ntt_mont_raw(conv, psi_ref[0, :], q, qn)
-    o_ref[0, 0, :] = jnp.where(mask_ref[0, 0, 0] != 0, pt_ref[0, :], res)
+    j, m = pl.program_id(0), pl.program_id(1)
+    alpha = y_ref.shape[0]
+    q, qn = q_ref[m, 0], qneg_ref[m, 0]
+    conv = _conv_tile([y_ref[a] for a in range(alpha)],
+                      [invd_ref[j, a, 0] for a in range(alpha)],
+                      [w_ref[j, m, a] for a in range(alpha)],
+                      d_ref[j, m, 0], q, qn)
+    res = core_ntt.ntt_tile(conv, tw_ref[...], q, qn)
+    o_ref[...] = jnp.where(mask_ref[j, m, 0] != 0, pt_ref[...], res)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def baseconv_ntt(y, w, d, inv_d, psi_m, q32, qneg, passthrough, mask, *,
-                 interpret: bool = True):
+def baseconv_ntt(y, w, d, inv_d, psi_tw, q32, qneg, passthrough, mask, *,
+                 interpret: bool):
     """Fused ModUp-BaseConv + forward NTT + own-row passthrough (the hoist).
 
     y: (nbeta*alpha, N) scaled digit coeffs (digit j at row block j);
     w: (nbeta, M, alpha) mont; d: (nbeta, M, 1) mont; inv_d: (nbeta,
-    alpha, 1) float; psi_m: (M, N); q32/qneg: (M, 1); passthrough: (M, N)
-    eval-domain c1 rows (selected where mask != 0). Returns digits
-    (nbeta, M, N) in eval domain."""
+    alpha, 1) float; psi_tw: (M, log2 N, Rt, C) twiddle tiles; q32/qneg:
+    (M, 1); passthrough: (M, N) eval-domain c1 rows (selected where
+    mask != 0). Returns digits (nbeta, M, N) in eval domain."""
     nbeta, M, alpha = w.shape
     N = y.shape[-1]
-    ydig = pl.BlockSpec((alpha, N), lambda j, _m: (j, 0))
-    wrow = pl.BlockSpec((1, 1, alpha), lambda j, m: (j, m, 0))
-    dcol = pl.BlockSpec((1, 1, 1), lambda j, m: (j, m, 0))
-    icol = pl.BlockSpec((1, alpha, 1), lambda j, _m: (j, 0, 0))
-    trow = pl.BlockSpec((1, N), lambda _j, m: (m, 0))
-    tcol = pl.BlockSpec((1, 1), lambda _j, m: (m, 0))
-    out = pl.BlockSpec((1, 1, N), lambda j, m: (j, m, 0))
-    return pl.pallas_call(
-        _baseconv_ntt_kernel,
-        grid=(nbeta, M),
-        in_specs=[ydig, wrow, dcol, icol, trow, tcol, tcol, trow, dcol],
-        out_specs=out,
-        out_shape=jax.ShapeDtypeStruct((nbeta, M, N), jnp.uint32),
-        interpret=interpret,
-    )(y, w, d, inv_d, psi_m, q32, qneg, passthrough, mask)
+    Rt, C = core_ntt.tile_shape(N)
+    ydig = pl.BlockSpec((alpha, Rt, C), lambda j, _m: (j, 0, 0))
+    tw = pl.BlockSpec((None,) + psi_tw.shape[1:], lambda _j, m: (m, 0, 0, 0))
+    trow = pl.BlockSpec((None, Rt, C), lambda _j, m: (m, 0, 0))
+    out = pl.BlockSpec((None, None, Rt, C), lambda j, m: (j, m, 0, 0))
+    smem = common.SMEM
+    with common.lowering_scope(interpret):
+        res = pl.pallas_call(
+            _baseconv_ntt_kernel,
+            grid=(nbeta, M),
+            in_specs=[ydig, smem, smem, smem, tw, smem, smem, trow, smem],
+            out_specs=out,
+            out_shape=jax.ShapeDtypeStruct((nbeta, M, Rt, C), jnp.uint32),
+            interpret=interpret,
+        )(common.tiles(y), w, d, inv_d, psi_tw, q32, qneg,
+          common.tiles(passthrough), mask)
+    return common.untiles(res)
 
 
-def _moddown_finish_kernel(x_ref, y_ref, w_ref, d_ref, invd_ref, psi_ref,
+def _moddown_finish_kernel(x_ref, y_ref, w_ref, d_ref, invd_ref, tw_ref,
                            pinv_ref, q_ref, qneg_ref, o_ref):
-    y = y_ref[...]                                  # (nd, N) resident
-    q, qn = q_ref[0, 0], qneg_ref[0, 0]
-    invd = invd_ref[...]                            # (nd, 1) fp
-    v = jnp.floor(jnp.sum(y.astype(invd.dtype) * invd, axis=0)
-                  + CORRECTION_EPS).astype(jnp.uint32)
-    prod = mm.montmul(y, w_ref[0, :][:, None], q, qn)
-    acc = mm.montsum(prod, q, axis=0)
-    corr = mm.montmul(v, d_ref[0, 0], q, qn)
-    conv = mm.montsub(acc, corr, q)
-    conv_eval = core_ntt.ntt_mont_raw(conv, psi_ref[0, :], q, qn)
-    diff = mm.montsub(x_ref[0, :], conv_eval, q)
-    o_ref[0, :] = mm.montmul(diff, pinv_ref[0, 0], q, qn)
+    r = pl.program_id(0)
+    nd = y_ref.shape[0]
+    q, qn = q_ref[r, 0], qneg_ref[r, 0]
+    conv = _conv_tile([y_ref[a] for a in range(nd)],
+                      [invd_ref[a, 0] for a in range(nd)],
+                      [w_ref[r, a] for a in range(nd)],
+                      d_ref[r, 0], q, qn)
+    conv_eval = core_ntt.ntt_tile(conv, tw_ref[...], q, qn)
+    diff = mm.montsub(x_ref[...], conv_eval, q)
+    o_ref[...] = mm.montmul(diff, pinv_ref[r, 0], q, qn)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def moddown_finish(x, y_drop, w, d, inv_d, psi_m, p_inv_m, q32, qneg, *,
-                   interpret: bool = True):
+def moddown_finish(x, y_drop, w, d, inv_d, psi_tw, p_inv_m, q32, qneg, *,
+                   interpret: bool):
     """Fused ModDown tail: BaseConv from the drop basis + NTT + sub + ·P⁻¹.
 
     x: (R, N) eval-domain target rows; y_drop: (nd, N) scaled drop-basis
     coeffs; w: (R, nd) mont; d/p_inv_m/q32/qneg: (R, 1); inv_d: (nd, 1)
-    float. Returns (R, N) eval-domain ModDown output."""
+    float; psi_tw: (R, log2 N, Rt, C) twiddle tiles. Returns (R, N)
+    eval-domain ModDown output."""
     R, N = x.shape
     nd = y_drop.shape[0]
-    row = pl.BlockSpec((1, N), lambda r: (r, 0))
-    full = pl.BlockSpec((nd, N), lambda _r: (0, 0))
-    wrow = pl.BlockSpec((1, nd), lambda r: (r, 0))
-    col = pl.BlockSpec((1, 1), lambda r: (r, 0))
-    icol = pl.BlockSpec((nd, 1), lambda _r: (0, 0))
-    return pl.pallas_call(
-        _moddown_finish_kernel,
-        grid=(R,),
-        in_specs=[row, full, wrow, col, icol, row, col, col, col],
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((R, N), jnp.uint32),
-        interpret=interpret,
-    )(x, y_drop, w, d, inv_d, psi_m, p_inv_m, q32, qneg)
+    Rt, C = core_ntt.tile_shape(N)
+    row = pl.BlockSpec((None, Rt, C), lambda r: (r, 0, 0))
+    full = pl.BlockSpec((nd, Rt, C), lambda _r: (0, 0, 0))
+    tw = pl.BlockSpec((None,) + psi_tw.shape[1:], lambda r: (r, 0, 0, 0))
+    smem = common.SMEM
+    with common.lowering_scope(interpret):
+        out = pl.pallas_call(
+            _moddown_finish_kernel,
+            grid=(R,),
+            in_specs=[row, full, smem, smem, smem, tw, smem, smem, smem],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct((R, Rt, C), jnp.uint32),
+            interpret=interpret,
+        )(common.tiles(x), common.tiles(y_drop), w, d, inv_d, psi_tw, p_inv_m,
+          q32, qneg)
+    return common.untiles(out)
 
 
 # ---------------------------------------------------------------------------
@@ -179,6 +203,7 @@ def _hoist_db_kernel(x_hbm, psii_ref, ninv_ref, hat_ref, qp_ref, qnp_ref,
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     R = nbeta * alpha
+    M = psi_ref.shape[0]
 
     # warm-up: ct 0's copy is started (and awaited) by step 0 itself;
     # ct b>0's copy was started by step b-1, so the wait below overlaps
@@ -187,71 +212,69 @@ def _hoist_db_kernel(x_hbm, psii_ref, ninv_ref, hat_ref, qp_ref, qnp_ref,
     def _():
         pltpu.make_async_copy(x_hbm.at[0], scratch.at[0], sem.at[0]).start()
 
-    slot = jax.lax.rem(b, jnp.int32(2))
+    slot = b % 2
     pltpu.make_async_copy(x_hbm.at[b], scratch.at[slot], sem.at[slot]).wait()
 
     @pl.when(b + 1 < nb)
     def _():
-        pltpu.make_async_copy(x_hbm.at[b + 1],
-                              scratch.at[jnp.int32(1) - slot],
-                              sem.at[jnp.int32(1) - slot]).start()
+        pltpu.make_async_copy(x_hbm.at[b + 1], scratch.at[1 - slot],
+                              sem.at[1 - slot]).start()
 
-    x = jnp.where(slot == 0, scratch[0], scratch[1])   # (R + M, N)
-    xd, c1f = x[:R], x[R:]
-    qp, qnp = qp_ref[...], qnp_ref[...]
-    y = mm.montmul(
-        core_ntt.intt_mont_raw(xd, psii_ref[...], ninv_ref[...], qp, qnp),
-        hat_ref[...], qp, qnp)
-    qf, qnf = qf_ref[...], qnf_ref[...]
-    psi = psi_ref[...]
+    x = scratch.at[slot]                                # (R + M, Rt, C)
+    ys = []
+    for r in range(R):
+        qp, qnp = qp_ref[r, 0], qnp_ref[r, 0]
+        coeff = core_ntt.intt_tile(x[r], psii_ref[r], ninv_ref[r, 0], qp, qnp)
+        ys.append(mm.montmul(coeff, hat_ref[r, 0], qp, qnp))
     for j in range(nbeta):
-        yj = y[j * alpha:(j + 1) * alpha]
-        invd = invd_ref[j]
-        v = jnp.floor(jnp.sum(yj.astype(invd.dtype) * invd, axis=0)
-                      + CORRECTION_EPS).astype(jnp.uint32)
-        prod = mm.montmul(yj[None], w_ref[j][:, :, None],
-                          qf[:, None], qnf[:, None])      # (M, alpha, N)
-        acc = mm.montsum(prod, qf[:, None], axis=1)
-        corr = mm.montmul(v[None], d_ref[j], qf, qnf)
-        conv = mm.montsub(acc, corr, qf)
-        res = core_ntt.ntt_mont_raw(conv, psi, qf, qnf)
-        o_ref[0, j] = jnp.where(mask_ref[j] != 0, c1f, res)
+        yj = ys[j * alpha:(j + 1) * alpha]
+
+        def target_row(m, carry, j=j, yj=yj):
+            q, qn = qf_ref[m, 0], qnf_ref[m, 0]
+            conv = _conv_tile(yj, [invd_ref[j, a, 0] for a in range(alpha)],
+                              [w_ref[j, m, a] for a in range(alpha)],
+                              d_ref[j, m, 0], q, qn)
+            res = core_ntt.ntt_tile(conv, psi_ref[m], q, qn)
+            o_ref[j, m] = jnp.where(mask_ref[j, m, 0] != 0, x[R + m], res)
+            return carry
+
+        jax.lax.fori_loop(0, M, target_row, 0)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("nbeta", "alpha", "interpret"))
-def hoist_db(xcat, psii_m, ninv_m, hat_m, q_pad, qneg_pad, w, d, inv_d,
-             psi_m, q_full, qneg_full, mask, *, nbeta: int, alpha: int,
-             interpret: bool = True):
+def hoist_db(xcat, psii_tw, ninv_m, hat_m, q_pad, qneg_pad, w, d, inv_d,
+             psi_tw, q_full, qneg_full, mask, *, nbeta: int, alpha: int,
+             interpret: bool):
     """Double-buffered batched hoist: grid over ciphertexts, 2-slot VMEM
     scratch so hoist(i+1)'s DMA overlaps transform(i).
 
     xcat: (B, nbeta*alpha + M, N) — per ct, the digit-padded c1 rows
     concatenated with the full-basis-padded c1 rows (passthrough source).
     Returns digits (B, nbeta, M, N)."""
-    B = xcat.shape[0]
-    M, N = psi_m.shape
-    whole = lambda *s: pl.BlockSpec(s, lambda _b: tuple(0 for _ in s))
-    return pl.pallas_call(
-        functools.partial(_hoist_db_kernel, nbeta=nbeta, alpha=alpha),
-        grid=(B,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  whole(nbeta * alpha, N), whole(nbeta * alpha, 1),
-                  whole(nbeta * alpha, 1), whole(nbeta * alpha, 1),
-                  whole(nbeta * alpha, 1),
-                  whole(nbeta, M, alpha), whole(nbeta, M, 1),
-                  whole(nbeta, alpha, 1),
-                  whole(M, N), whole(M, 1), whole(M, 1),
-                  whole(nbeta, M, 1)],
-        out_specs=pl.BlockSpec((1, nbeta, M, N), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nbeta, M, N), jnp.uint32),
-        scratch_shapes=[
-            pltpu.VMEM((2, nbeta * alpha + M, N), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(xcat, psii_m, ninv_m, hat_m, q_pad, qneg_pad, w, d, inv_d, psi_m,
-      q_full, qneg_full, mask)
+    B, rows, N = xcat.shape
+    M = psi_tw.shape[0]
+    Rt, C = core_ntt.tile_shape(N)
+    whole = lambda a: pl.BlockSpec(a.shape, lambda _b: (0,) * a.ndim)
+    smem = common.SMEM
+    with common.lowering_scope(interpret):
+        out = pl.pallas_call(
+            functools.partial(_hoist_db_kernel, nbeta=nbeta, alpha=alpha),
+            grid=(B,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), whole(psii_tw),
+                      smem, smem, smem, smem, smem, smem, smem,
+                      whole(psi_tw), smem, smem, smem],
+            out_specs=pl.BlockSpec((None, nbeta, M, Rt, C),
+                                   lambda b: (b, 0, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, nbeta, M, Rt, C), jnp.uint32),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, Rt, C), jnp.uint32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+            interpret=interpret,
+        )(common.tiles(xcat), psii_tw, ninv_m, hat_m, q_pad, qneg_pad, w, d,
+          inv_d, psi_tw, q_full, qneg_full, mask)
+    return common.untiles(out)
 
 
 # ---------------------------------------------------------------------------
@@ -313,11 +336,12 @@ def build_hoist_tables(ctx, tools, level: int, fp_dtype=np.float64) -> dict:
     rows_full = list(full)
     return dict(
         nbeta=nbeta, alpha=alpha, nq=level + 1,
-        psii_pad=jnp.asarray(psii_pad), ninv_pad=jnp.asarray(ninv_pad),
+        psii_tw=jnp.asarray(core_ntt.expand_twiddles(psii_pad, inverse=True)),
+        ninv_pad=jnp.asarray(ninv_pad),
         q_pad=jnp.asarray(q_pad), qneg_pad=jnp.asarray(qneg_pad),
         hat_pad=jnp.asarray(hat_pad), w=jnp.asarray(w),
         d=jnp.asarray(dmod), inv_d=jnp.asarray(inv_d),
-        psi_full=jnp.asarray(psi_np[rows_full]),
+        psi_tw=jnp.asarray(core_ntt.expand_twiddles(psi_np[rows_full])),
         q_full=jnp.asarray(q32_np[rows_full][:, None]),
         qneg_full=jnp.asarray(qneg_np[rows_full][:, None]),
         mask=jnp.asarray(mask),
@@ -347,7 +371,8 @@ def build_moddown_tables(ctx, tools, level: int,
     rows_p, rows_q = list(P), list(Q)
     return dict(
         drop_idx=drop_idx, n_out=len(Q),
-        psii_drop=jnp.asarray(psii_np[rows_p]),
+        psii_tw=jnp.asarray(core_ntt.expand_twiddles(psii_np[rows_p],
+                                                     inverse=True)),
         ninv_drop=jnp.asarray(ninv_np[rows_p][:, None]),
         q_drop=jnp.asarray(q32_np[rows_p][:, None]),
         qneg_drop=jnp.asarray(qneg_np[rows_p][:, None]),
@@ -355,7 +380,7 @@ def build_moddown_tables(ctx, tools, level: int,
         w=jnp.asarray(_mont_col(W, qs[rows_q][:, None])),
         d=jnp.asarray(_mont_col(D_mod_t, qs[rows_q][:, None])),
         inv_d=jnp.asarray(invd.astype(fp_dtype)),
-        psi_out=jnp.asarray(psi_np[rows_q]),
+        psi_tw=jnp.asarray(core_ntt.expand_twiddles(psi_np[rows_q])),
         q_out=jnp.asarray(q32_np[rows_q][:, None]),
         qneg_out=jnp.asarray(qneg_np[rows_q][:, None]),
         p_inv=jnp.asarray(_mont_col(p_inv[:, 0], qs[rows_q])[:, None]),
@@ -367,46 +392,46 @@ def build_moddown_tables(ctx, tools, level: int,
 # ---------------------------------------------------------------------------
 
 
-def hoist_fused(c1, t: dict, *, interpret: bool = True):
+def hoist_fused(c1, t: dict, *, interpret: bool):
     """Fused Decomp→iNTT→ModUp-BaseConv→NTT: c1 (nq, N) eval-domain main
     limbs -> digits (nbeta, M, N) eval-domain (own rows passed through)."""
     nq = c1.shape[0]
-    R = t["psii_pad"].shape[0]
-    M = t["psi_full"].shape[0]
+    R = t["psii_tw"].shape[0]
+    M = t["psi_tw"].shape[0]
     x_dig = jnp.pad(c1, ((0, R - nq), (0, 0)))
-    y = intt_scale(x_dig, t["psii_pad"], t["ninv_pad"], t["hat_pad"],
+    y = intt_scale(x_dig, t["psii_tw"], t["ninv_pad"], t["hat_pad"],
                    t["q_pad"], t["qneg_pad"], interpret=interpret)
     c1f = jnp.pad(c1, ((0, M - nq), (0, 0)))
-    return baseconv_ntt(y, t["w"], t["d"], t["inv_d"], t["psi_full"],
+    return baseconv_ntt(y, t["w"], t["d"], t["inv_d"], t["psi_tw"],
                         t["q_full"], t["qneg_full"], c1f, t["mask"],
                         interpret=interpret)
 
 
-def hoist_fused_db(c1s, t: dict, *, interpret: bool = True):
+def hoist_fused_db(c1s, t: dict, *, interpret: bool):
     """Double-buffered batched fused hoist: c1s (B, nq, N) -> (B, nbeta,
     M, N). Same math as vmap(hoist_fused); the DMA of ct i+1 overlaps the
     transform of ct i."""
     B, nq, _N = c1s.shape
-    R = t["psii_pad"].shape[0]
-    M = t["psi_full"].shape[0]
+    R = t["psii_tw"].shape[0]
+    M = t["psi_tw"].shape[0]
     xcat = jnp.concatenate(
         [jnp.pad(c1s, ((0, 0), (0, R - nq), (0, 0))),
          jnp.pad(c1s, ((0, 0), (0, M - nq), (0, 0)))], axis=1)
-    return hoist_db(xcat, t["psii_pad"], t["ninv_pad"], t["hat_pad"],
+    return hoist_db(xcat, t["psii_tw"], t["ninv_pad"], t["hat_pad"],
                     t["q_pad"], t["qneg_pad"], t["w"], t["d"], t["inv_d"],
-                    t["psi_full"], t["q_full"], t["qneg_full"], t["mask"],
+                    t["psi_tw"], t["q_full"], t["qneg_full"], t["mask"],
                     nbeta=t["nbeta"], alpha=t["alpha"], interpret=interpret)
 
 
-def moddown_fused(x_full, t: dict, *, interpret: bool = True):
+def moddown_fused(x_full, t: dict, *, interpret: bool):
     """Fused merged ModDown+Rescale: x_full (nq+k, N) eval-domain extended
     limbs at level ℓ -> (ℓ, N) eval-domain over Q_{ℓ-1}."""
     x_drop = x_full[t["drop_idx"]]
-    y = intt_scale(x_drop, t["psii_drop"], t["ninv_drop"], t["hat_drop"],
+    y = intt_scale(x_drop, t["psii_tw"], t["ninv_drop"], t["hat_drop"],
                    t["q_drop"], t["qneg_drop"], interpret=interpret)
     n_out = t["n_out"]
     return moddown_finish(x_full[:n_out], y, t["w"], t["d"], t["inv_d"],
-                          t["psi_out"], t["p_inv"], t["q_out"],
+                          t["psi_tw"], t["p_inv"], t["q_out"],
                           t["qneg_out"], interpret=interpret)
 
 
@@ -415,21 +440,23 @@ def moddown_fused(x_full, t: dict, *, interpret: bool = True):
 # ---------------------------------------------------------------------------
 
 
-def hoist_working_set_rows(nbeta: int, alpha: int) -> int:
+def hoist_working_set_rows(nbeta: int, alpha: int, logn: int) -> int:
     """Peak per-grid-step resident rows of the fused hoist (stage 2
-    dominates): the digit's alpha scaled rows + out/psi/passthrough rows."""
-    return alpha + 3
+    dominates): the digit's alpha scaled rows + out/passthrough rows + the
+    target row's log2(N) twiddle rows."""
+    return alpha + 2 + logn
 
 
-def hoist_db_working_set_rows(nbeta: int, alpha: int, m_ext: int) -> int:
+def hoist_db_working_set_rows(nbeta: int, alpha: int, m_ext: int,
+                              logn: int) -> int:
     """Resident rows of the double-buffered hoist: 2-slot ct scratch +
-    twiddle tables + one ct's digit output."""
-    scratch = 2 * (nbeta * alpha + m_ext)
-    tables = nbeta * alpha + m_ext
-    return scratch + tables + nbeta * m_ext
+    every row's log2(N) twiddle rows + one ct's digit output."""
+    rows = nbeta * alpha + m_ext
+    return 2 * rows + rows * logn + nbeta * m_ext
 
 
-def moddown_working_set_rows(nd: int) -> int:
+def moddown_working_set_rows(nd: int, logn: int) -> int:
     """Peak per-grid-step resident rows of the fused ModDown tail: the
-    nd drop-basis rows (resident across the output grid) + x/psi/out."""
-    return nd + 3
+    nd drop-basis rows (resident across the output grid) + x/out + the
+    target row's log2(N) twiddle rows."""
+    return nd + 2 + logn

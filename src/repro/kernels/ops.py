@@ -1,8 +1,8 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run with interpret=True; on TPU set
-interpret=False (the default flips on backend detection). ref.py holds the
-pure-jnp oracles used by the allclose tests.
+Kernels run compiled (``interpret=False``) whenever the default backend is a
+TPU, and in Pallas interpret mode everywhere else (the CPU test runs).
+ref.py holds the pure-jnp oracles used by the allclose tests.
 """
 from __future__ import annotations
 

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,7 +17,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     need = int(np.prod(shape))
     devs = jax.devices()
     assert len(devs) >= need, (len(devs), need)
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(num_devices: int, model_parallel: int = 1,
@@ -24,4 +26,5 @@ def make_mesh_for(num_devices: int, model_parallel: int = 1,
     """Small helper for CPU tests (e.g. 8 host devices: 4×2)."""
     devs = jax.devices()[:num_devices]
     return jax.make_mesh((num_devices // model_parallel, model_parallel),
-                         axis_names, devices=devs)
+                         axis_names, devices=devs,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -12,6 +12,7 @@ import jax
 import repro  # noqa: F401
 from repro.configs import get_config, get_smoke_config
 from repro.distributed.sharding import make_rules, set_rules
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models import transformer as tf
 from repro.serve.engine import ContinuousBatcher, ServeConfig
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--tp", type=int, default=1)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_mesh_for(args.tp, model_parallel=args.tp)

@@ -21,6 +21,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, PrefetchLoader
 from repro.distributed.fault import FaultConfig, StragglerDetector
 from repro.distributed.sharding import make_rules, set_rules
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh_for, make_production_mesh
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import (TrainConfig, init_train_state,
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.production_mesh:

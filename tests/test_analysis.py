@@ -167,7 +167,6 @@ def test_jaxpr_lint_rejects_two_collective_program():
     the sole-collective contract (DESIGN.md §4) on both counts."""
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("model",))
@@ -177,8 +176,8 @@ def test_jaxpr_lint_rejects_two_collective_program():
         z = jax.lax.psum(y * 2.0, "model")
         return jax.lax.all_gather(z, "model")
 
-    f = shard_map(bad, mesh=mesh, in_specs=P(), out_specs=P(None),
-                  check_rep=False)
+    f = jax.shard_map(bad, mesh=mesh, in_specs=P(), out_specs=P(None),
+                      check_vma=False)
     diags = lint_jaxpr(jax.make_jaxpr(f)(jnp.ones(4)),
                        datapath="xla", expected_psums=2,
                        program="test", stage="sharded[xla]")

@@ -51,11 +51,12 @@ def test_pallas_ntt_matches_u64_and_roundtrips(setup):
     view = eng.basis(np.arange(eng.params.num_total))
     x = _rand_limbs(rng, view, eng.params.N)
     xj = jnp.asarray(x)[None]
-    fwd = kntt.ntt(xj, view.psi_brv_mont, view.moduli_u32, view.qneg_inv)
+    fwd = kntt.ntt(xj, view.psi_brv_mont, view.moduli_u32, view.qneg_inv,
+                   interpret=True)
     want = core_ntt.ntt(jnp.asarray(x), view.psi_brv, view.moduli)
     np.testing.assert_array_equal(np.asarray(fwd[0]), np.asarray(want))
     back = kntt.intt(fwd, view.psi_inv_brv_mont, view.n_inv_mont,
-                     view.moduli_u32, view.qneg_inv)
+                     view.moduli_u32, view.qneg_inv, interpret=True)
     np.testing.assert_array_equal(np.asarray(back[0]), x)
 
 
@@ -210,8 +211,63 @@ def test_fused_stage_working_sets_cover_new_stages():
     assert set(ws) == {"rot", "hoist", "moddown"}
     alpha = min(p.alpha, 3)
     assert ws["hoist"] == basechange.hoist_working_set_rows(
-        p.beta, alpha) * 4 * p.N
+        p.beta, alpha, p.logN) * 4 * p.N
     assert ws["moddown"] == basechange.moddown_working_set_rows(
-        p.k + 1) * 4 * p.N
+        p.k + 1, p.logN) * 4 * p.N
     assert fused_working_set_bytes(p, nbeta=p.beta, chunk=4,
                                    level=2) == max(ws.values())
+
+
+# -- the chip's f32 BaseConv correction -----------------------------------
+
+
+def _smoke_tolerance() -> float:
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.TOLERANCE
+
+
+def test_f32_basechange_tables_vs_u64_chain(setup):
+    """Off the CPU the fused hoist and merged ModDown run their BaseConv
+    overflow count in f32 (CkksEngine._fp_dtype). Pin that arithmetic here,
+    in interpret mode, against the u64 XLA chain: the f32 count may be off
+    by one where a coefficient's true count sits within ~2^-21 of an
+    integer, so coefficients may differ (printed), but only rarely, and an
+    HE MM through the f32 tables stays within the chip smoke's tolerance."""
+    from repro.core.compile import HEContext, compile_hemm
+    from repro.core.hemm import decrypt_matrix, encrypt_matrix, plan_hemm
+
+    eng, ct, rng = setup["eng"], setup["ct"], setup["rng"]
+    p = eng.params
+    ell = p.L
+    f32 = CkksEngine(p)
+    f32._fp_dtype = lambda: np.float32          # the tables the chip builds
+    assert f32.fused_hoist_tables(ell)["inv_d"].dtype == np.float32
+
+    got = hlt_mod.hoist(f32, ct, datapath="pallas").digits
+    want = hlt_mod.hoist(eng, ct, datapath="xla").digits
+    acc = jnp.asarray(_rand_limbs(rng, eng.basis(
+        list(range(ell + 1)) + list(range(p.num_main, p.num_total))), p.N))
+    got_md = f32._mod_down_eval(acc, ell, drop_last=True, datapath="pallas")
+    want_md = eng._mod_down_eval(acc, ell, drop_last=True, datapath="xla")
+    diff = (int(np.sum(np.asarray(got) != np.asarray(want))),
+            int(np.sum(np.asarray(got_md) != np.asarray(want_md))))
+    total = (got.size, got_md.size)
+    print(f"f32 vs u64: hoist {diff[0]}/{total[0]}, "
+          f"moddown {diff[1]}/{total[1]} coefficients differ")
+    assert diff[0] <= total[0] // 1000 and diff[1] <= total[1] // 1000
+
+    m, l, n = 4, 3, 5
+    ctx = HEContext(f32, verify="error")
+    plan = plan_hemm(f32, m, l, n)
+    ctx.keygen(rng, rot_steps=plan.rot_steps)
+    A, B = rng.uniform(-1, 1, (m, l)), rng.uniform(-1, 1, (l, n))
+    prog = compile_hemm(ctx, plan, schedule="pallas")
+    C = decrypt_matrix(f32, ctx.keys, prog(
+        encrypt_matrix(f32, ctx.keys, A, rng),
+        encrypt_matrix(f32, ctx.keys, B, rng)), m, n)
+    assert np.max(np.abs(C - A @ B)) <= _smoke_tolerance()
