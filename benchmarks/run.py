@@ -25,7 +25,6 @@ quantity: counts, MB, speedups, ...). Sections:
              (wall time + the decrypt/re-encrypt round-trips it removes),
              per-hop levels and operand bytes (BENCH_chain.json)
   kernels  — Pallas kernel calls (interpret mode) vs jnp oracle
-  roofline — §Roofline table from results/dryrun/*.json (if present)
 
 Flags:
   --json [PATH]  also write machine-readable results: hemm/fig6 data to PATH
@@ -62,7 +61,7 @@ SPLIT_SECTIONS = ("blockmm", "dist", "serve", "chain")
 # run loudly instead of silently breaking the comparison.
 BENCH_SCHEMA = {
     "hemm": ("shape", "logN", "hlt_us_per_schedule", "hemm_us_per_schedule",
-             "stage_us_per_datapath", "step2_operand_bytes", "step2_plan"),
+             "step2_operand_bytes", "step2_plan"),
     "blockmm": ("shape", "loop_us", "batched_us", "step1_operand_bytes",
                 "step1_slots", "schedule"),
     "dist": ("batch", "logN", "per_device_count"),
@@ -184,25 +183,6 @@ def bench_fig6_schedules(smoke: bool = False):
         row(f"fig6/hlt/{sched}", hlt_us[sched],
             f"speedup_vs_baseline={hlt_us['baseline'] / hlt_us[sched]:.2f}x")
 
-    # per-stage base-change timings, fused Pallas vs XLA lowering (§7 knob):
-    # hoist = Decomp→iNTT→BaseConv→NTT, moddown = the merged ModDown+Rescale
-    # tail.  (On CPU the fused path runs in the Pallas interpreter, so the
-    # trajectory — not the ratio — is the signal; on TPU this measures the
-    # actual datapath.)
-    from repro.core import hlt as hlt_mod
-    acc = hlt_mod.hoist(eng, ctA, datapath="xla").c0_ext
-    stage_us = {}
-    for dp in ("pallas", "xla"):
-        us_h, _ = _t(lambda dp=dp: (lambda h: (h.digits, h.c0_ext, h.c1_ext))(
-            hlt_mod.hoist(eng, ctA, datapath=dp)), reps=reps)
-        us_m, _ = _t(lambda dp=dp: eng._mod_down_eval(
-            acc, ctA.level, drop_last=True, datapath=dp), reps=reps)
-        stage_us[dp] = {"hoist": round(us_h, 1), "moddown": round(us_m, 1)}
-    for st in ("hoist", "moddown"):
-        row(f"fig6/stage/{st}", stage_us["pallas"][st],
-            f"xla_us={stage_us['xla'][st]};"
-            f"fused_vs_xla={stage_us['xla'][st] / stage_us['pallas'][st]:.2f}x")
-
     prog_mo = compile_hemm(ctx, plan, schedule="mo")
     prog_pl = compile_hemm(ctx, plan, schedule="pallas")
     us_mm, _ = _t(lambda: prog_mo(ctA, ctB), reps=1)
@@ -229,7 +209,6 @@ def bench_fig6_schedules(smoke: bool = False):
         "hlt_us_per_schedule": {k: round(v, 1) for k, v in hlt_us.items()},
         "hemm_us_per_schedule": {"mo": round(us_mm, 1),
                                  "pallas": round(us_mmp, 1)},
-        "stage_us_per_datapath": stage_us,
         "step2_operand_bytes": {
             "diag_dedup": s2.operand_bytes,
             "diag_naive": s2.operand_bytes_naive,
@@ -577,25 +556,6 @@ def bench_kernels():
     row("kernels/ntt", us, f"N={p.N} M={M}")
 
 
-def bench_roofline():
-    import glob
-    import json
-    import os
-    base = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
-    files = sorted(glob.glob(os.path.join(base, "*__pod.json")))
-    for f in files[:50]:
-        r = json.load(open(f))
-        if not r.get("ok") or "roofline" in r and r.get("skipped"):
-            continue
-        t = r.get("roofline")
-        if not t:
-            continue
-        dom = r.get("dominant", "?")
-        row(f"roofline/{r['arch']}/{r['shape']}", None,
-            f"compute={t['compute_s']:.2e}s;memory={t['memory_s']:.2e}s;"
-            f"collective={t['collective_s']:.2e}s;dom={dom}")
-
-
 def main() -> None:
     import inspect
 
@@ -613,7 +573,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     sections = [bench_table1, bench_table2_costmodel, bench_fig6_schedules,
                 bench_blockmm, bench_dist, bench_serve, bench_chain,
-                bench_kernels, bench_roofline]
+                bench_kernels]
     for fn in sections:
         if args.section and args.section not in fn.__name__:
             continue

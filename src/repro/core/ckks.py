@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.core import automorph, modmath as mm, ntt
 from repro.core.params import HEParams, PrimeContext, get_context
 from repro.core.rns import RnsTools
+from repro.core.spans import span
 from repro.kernels import basechange, ops
 
 
@@ -217,28 +218,29 @@ class CkksEngine:
     # -- keygen ---------------------------------------------------------------
 
     def keygen(self, rng: np.random.Generator, rot_steps=()) -> Keys:
-        p = self.params
-        full = list(range(p.num_total))
-        s_int = rng.integers(-1, 2, size=p.N).astype(np.int64)
-        s_eval = self._small_poly_eval(s_int, full)
-        s2_int = None  # s^2 handled in eval domain below
+        with span("ckks.keygen"):
+            p = self.params
+            full = list(range(p.num_total))
+            s_int = rng.integers(-1, 2, size=p.N).astype(np.int64)
+            s_eval = self._small_poly_eval(s_int, full)
+            s2_int = None  # s^2 handled in eval domain below
 
-        # s^2 over full basis (eval-domain product)
-        view = self.basis(full)
-        s2_eval = mm.mulmod(s_eval, s_eval, view.moduli)
+            # s^2 over full basis (eval-domain product)
+            view = self.basis(full)
+            s2_eval = mm.mulmod(s_eval, s_eval, view.moduli)
 
-        evk_mult = self._make_evk(rng, s_eval, s2_eval)
-        rot, galois = {}, {}
-        for r in rot_steps:
-            g = automorph.galois_elt_rot(r, p.N)
-            if g in galois:
-                rot[r] = galois[g]
-                continue
-            s_rot = automorph.apply_eval(s_eval, p.N, g)
-            k = self._make_evk(rng, s_eval, s_rot)
-            rot[r] = k
-            galois[g] = k
-        return Keys(s_eval=s_eval, evk_mult=evk_mult, rot=rot, galois=galois)
+            evk_mult = self._make_evk(rng, s_eval, s2_eval)
+            rot, galois = {}, {}
+            for r in rot_steps:
+                g = automorph.galois_elt_rot(r, p.N)
+                if g in galois:
+                    rot[r] = galois[g]
+                    continue
+                s_rot = automorph.apply_eval(s_eval, p.N, g)
+                k = self._make_evk(rng, s_eval, s_rot)
+                rot[r] = k
+                galois[g] = k
+            return Keys(s_eval=s_eval, evk_mult=evk_mult, rot=rot, galois=galois)
 
     def _make_evk(self, rng: np.random.Generator, s_eval, sprime_eval) -> EvalKey:
         """evk_j = (-a_j s + e_j + W_j s', a_j) over the full basis, where
@@ -310,11 +312,12 @@ class CkksEngine:
     # -- homomorphic ops ------------------------------------------------------
 
     def add(self, a: Ciphertext, b: Ciphertext) -> Ciphertext:
-        assert a.level == b.level, (a.level, b.level)
-        view = self.main_basis(a.level)
-        return Ciphertext(mm.addmod(a.c0, b.c0, view.moduli),
-                          mm.addmod(a.c1, b.c1, view.moduli),
-                          a.level, max(a.scale, b.scale))
+        with span("ckks.add"):
+            assert a.level == b.level, (a.level, b.level)
+            view = self.main_basis(a.level)
+            return Ciphertext(mm.addmod(a.c0, b.c0, view.moduli),
+                              mm.addmod(a.c1, b.c1, view.moduli),
+                              a.level, max(a.scale, b.scale))
 
     def sub(self, a: Ciphertext, b: Ciphertext) -> Ciphertext:
         view = self.main_basis(a.level)
@@ -337,17 +340,18 @@ class CkksEngine:
     def mult(self, a: Ciphertext, b: Ciphertext, keys: Keys) -> Ciphertext:
         """ct × ct with relinearization (no rescale — call rescale() after,
         mirroring paper Algorithm 1/2 structure)."""
-        assert a.level == b.level
-        ell = a.level
-        view = self.main_basis(ell)
-        d0 = mm.mulmod(a.c0, b.c0, view.moduli)
-        d1 = mm.addmod(mm.mulmod(a.c0, b.c1, view.moduli),
-                       mm.mulmod(a.c1, b.c0, view.moduli), view.moduli)
-        d2 = mm.mulmod(a.c1, b.c1, view.moduli)
-        k0, k1 = self.key_switch(d2, keys.evk_mult, ell)
-        return Ciphertext(mm.addmod(d0, k0, view.moduli),
-                          mm.addmod(d1, k1, view.moduli),
-                          ell, a.scale * b.scale)
+        with span("ckks.mult"):
+            assert a.level == b.level
+            ell = a.level
+            view = self.main_basis(ell)
+            d0 = mm.mulmod(a.c0, b.c0, view.moduli)
+            d1 = mm.addmod(mm.mulmod(a.c0, b.c1, view.moduli),
+                           mm.mulmod(a.c1, b.c0, view.moduli), view.moduli)
+            d2 = mm.mulmod(a.c1, b.c1, view.moduli)
+            k0, k1 = self.key_switch(d2, keys.evk_mult, ell)
+            return Ciphertext(mm.addmod(d0, k0, view.moduli),
+                              mm.addmod(d1, k1, view.moduli),
+                              ell, a.scale * b.scale)
 
     def rotate(self, ct: Ciphertext, r: int, keys: Keys) -> Ciphertext:
         """Rot(ct, r): circular left rotation of slots by r."""
@@ -364,28 +368,29 @@ class CkksEngine:
 
     def key_switch(self, d, evk: EvalKey, ell: int):
         """d: (ell+1, N) eval-domain poly under s'; returns (k0, k1) under s."""
-        p = self.params
-        bases = self.tools.digit_bases(ell)
-        full = bases[0][2]
-        fview = self.basis(full)
-        acc0 = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
-        acc1 = jnp.zeros_like(acc0)
-        for j, (own, gen, _) in enumerate(bases):
-            dig_eval = d[own[0]: own[-1] + 1]
-            coeff = self._intt(dig_eval, self.basis(own))
-            ext = self.tools.mod_up(coeff, own, gen)
-            ext_eval = self._ntt(ext, self.basis(gen))
-            # assemble digit over full basis (reuse own eval limbs directly)
-            pos = {g: i for i, g in enumerate(full)}
-            xfull = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
-            xfull = xfull.at[np.array([pos[i] for i in own])].set(dig_eval)
-            xfull = xfull.at[np.array([pos[i] for i in gen])].set(ext_eval)
-            rows = np.array(full)
-            acc0 = mm.addmod(acc0, mm.mulmod(xfull, evk.k0[j][rows], fview.moduli),
-                             fview.moduli)
-            acc1 = mm.addmod(acc1, mm.mulmod(xfull, evk.k1[j][rows], fview.moduli),
-                             fview.moduli)
-        return self._mod_down_eval(acc0, ell), self._mod_down_eval(acc1, ell)
+        with span("ckks.key_switch"):
+            p = self.params
+            bases = self.tools.digit_bases(ell)
+            full = bases[0][2]
+            fview = self.basis(full)
+            acc0 = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
+            acc1 = jnp.zeros_like(acc0)
+            for j, (own, gen, _) in enumerate(bases):
+                dig_eval = d[own[0]: own[-1] + 1]
+                coeff = self._intt(dig_eval, self.basis(own))
+                ext = self.tools.mod_up(coeff, own, gen)
+                ext_eval = self._ntt(ext, self.basis(gen))
+                # assemble digit over full basis (reuse own eval limbs directly)
+                pos = {g: i for i, g in enumerate(full)}
+                xfull = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
+                xfull = xfull.at[np.array([pos[i] for i in own])].set(dig_eval)
+                xfull = xfull.at[np.array([pos[i] for i in gen])].set(ext_eval)
+                rows = np.array(full)
+                acc0 = mm.addmod(acc0, mm.mulmod(xfull, evk.k0[j][rows], fview.moduli),
+                                 fview.moduli)
+                acc1 = mm.addmod(acc1, mm.mulmod(xfull, evk.k1[j][rows], fview.moduli),
+                                 fview.moduli)
+            return self._mod_down_eval(acc0, ell), self._mod_down_eval(acc1, ell)
 
     def _mod_down_eval(self, x_full, ell: int, drop_last: bool = False,
                        datapath: Optional[str] = None):
@@ -422,11 +427,12 @@ class CkksEngine:
 
     def rescale(self, ct: Ciphertext) -> Ciphertext:
         """Divide by q_ℓ, dropping one level (eval-domain single-limb path)."""
-        ell = ct.level
-        q_ell = self.ctx.moduli_host[ell]
-        c0 = self._rescale_poly(ct.c0, ell)
-        c1 = self._rescale_poly(ct.c1, ell)
-        return Ciphertext(c0, c1, ell - 1, ct.scale / q_ell)
+        with span("ckks.rescale"):
+            ell = ct.level
+            q_ell = self.ctx.moduli_host[ell]
+            c0 = self._rescale_poly(ct.c0, ell)
+            c1 = self._rescale_poly(ct.c1, ell)
+            return Ciphertext(c0, c1, ell - 1, ct.scale / q_ell)
 
     def _rescale_poly(self, x, ell: int):
         last_coeff = self._intt(x[ell:ell + 1], self.basis((ell,)))

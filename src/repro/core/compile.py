@@ -45,6 +45,7 @@ from repro.core.costmodel import (VMEM_HEADROOM, hlt_hoist_bytes,
                                   select_chain_schedules, select_schedule,
                                   sharded_collective_bytes)
 from repro.core.hlt import DiagSet, Hoisted, hoist, hoist_batched
+from repro.core.spans import span
 from repro.distributed.sharding import logical_axis_size, make_rules
 
 
@@ -665,23 +666,31 @@ class CompiledHLT:
             assert isinstance(item, Ciphertext), \
                 "schedule='baseline' has no hoisting product; pass Ciphertexts"
             assert item.level == plan.level
-            return hlt_mod._hlt_baseline(eng, item, ds, ctx.keys)
-        hst = item if isinstance(item, Hoisted) else \
-            hoist(eng, item, datapath=plan.datapath)
+            with span("hlt.launch"):
+                return hlt_mod._hlt_baseline(eng, item, ds, ctx.keys)
+        if isinstance(item, Hoisted):
+            hst = item
+        else:
+            with span("hlt.hoist"):
+                hst = hoist(eng, item, datapath=plan.datapath)
         assert hst.level == plan.level, (hst.level, plan.level)
         if plan.schedule == "hoisted":
-            return hlt_mod._hlt_hoisted(eng, hst, ds, ctx.keys)
+            with span("hlt.launch"):
+                return hlt_mod._hlt_hoisted(eng, hst, ds, ctx.keys)
         if plan.schedule == "mo":
-            return hlt_mod._hlt_mo(eng, hst, ds, ctx.keys, plan.chunk,
-                                   ctx._jit)
+            with span("hlt.launch"):
+                return hlt_mod._hlt_mo(eng, hst, ds, ctx.keys, plan.chunk,
+                                       ctx._jit)
         if operands is None:            # single-DiagSet operands from arena
             operands = ctx.arena.slot(
                 "pallas_operands", ds, (plan.level, plan.nbeta, plan.d_pad),
                 lambda: hlt_mod._build_pallas_operands(
                     eng, ds, ctx.keys, plan.level, plan.nbeta, plan.d_pad))[1]
         fn = ctx._pallas_pipeline(plan.level, plan.chunk, "single")
-        c0, c1 = fn(hst.digits, hst.c0_ext, hst.c1_ext, *operands)
-        return self._finish(c0, c1, hst.scale, ds)
+        with span("hlt.launch"):
+            c0, c1 = fn(hst.digits, hst.c0_ext, hst.c1_ext, *operands)
+        with span("hlt.finish"):
+            return self._finish(c0, c1, hst.scale, ds)
 
     @property
     def _datapath(self) -> str:
@@ -758,14 +767,17 @@ class CompiledHLT:
     def _run_sharded(self, items) -> list:
         ctx, plan = self.ctx, self.plan
         tabs, _ = self._sharded
-        args, layout = self._sharded_args(items)
+        with span("hlt.hoist"):
+            args, layout = self._sharded_args(items)
         fn = ctx._sharded_pipeline(tabs, plan.d_pad, plan.nbeta,
                                    self._datapath, plan.chunk, layout,
                                    plan.datapath)
-        out0, out1 = fn(args)
+        with span("hlt.launch"):
+            out0, out1 = fn(args)
         lvl = plan.level
-        return [self._finish(out0[b, :lvl], out1[b, :lvl], it.scale, ds)
-                for b, (it, ds) in enumerate(zip(items, self._diags, strict=True))]
+        with span("hlt.finish"):
+            return [self._finish(out0[b, :lvl], out1[b, :lvl], it.scale, ds)
+                    for b, (it, ds) in enumerate(zip(items, self._diags, strict=True))]
 
     def hlo(self, items) -> str:
         """Optimized HLO text of the batched program this call would run
@@ -801,10 +813,13 @@ class CompiledHLT:
         return fn, args, hoisted, ct_slots
 
     def _run_batched_pallas(self, items) -> list:
-        fn, args, hoisted, ct_slots = self._batched_pallas_args(items)
-        c0b, c1b = fn(*args)
-        return [self._finish(c0b[b], c1b[b], hoisted[ct_slots[b]].scale, ds)
-                for b, ds in enumerate(self._diags)]
+        with span("hlt.hoist"):
+            fn, args, hoisted, ct_slots = self._batched_pallas_args(items)
+        with span("hlt.launch"):
+            c0b, c1b = fn(*args)
+        with span("hlt.finish"):
+            return [self._finish(c0b[b], c1b[b], hoisted[ct_slots[b]].scale, ds)
+                    for b, ds in enumerate(self._diags)]
 
 
 # ---------------------------------------------------------------------------
@@ -888,29 +903,36 @@ class HEMMProgram:
         self._gen = ctx._generation
 
     def __call__(self, ctA: Ciphertext, ctB: Ciphertext) -> Ciphertext:
-        self.ctx._check_generation(self._gen)
-        self.ctx.counters["program_launches"] += 1
-        eng, keys, p = self.ctx.eng, self.ctx.keys, self.mm_plan
-        assert ctA.level == ctB.level == self.plan.level
-        if self.plan.batched:
-            outs = self._step2(self._step2_items(ctA, ctB))
-        else:
-            s1a, s1b = self._step1
-            ctA0, ctB0 = s1a(ctA), s1b(ctB)
-            if self.plan.schedule == "baseline" or \
-                    self.plan.schedule.startswith("sharded"):
-                inA, inB = ctA0, ctB0
-            else:   # hoist once, reuse across all l Step-2 HLTs per input
-                dp = self.plan.step2.datapath
-                inA = hoist(eng, ctA0, datapath=dp)
-                inB = hoist(eng, ctB0, datapath=dp)
-            outs = ([run(inA) for run in self._step2[:p.l]]
-                    + [run(inB) for run in self._step2[p.l:]])
-        acc: Optional[Ciphertext] = None
-        for k in range(p.l):
-            prod = eng.rescale(eng.mult(outs[k], outs[p.l + k], keys))
-            acc = prod if acc is None else eng.add(acc, prod)
-        return acc
+        with span("hemm.call"):
+            self.ctx._check_generation(self._gen)
+            self.ctx.counters["program_launches"] += 1
+            eng, keys, p = self.ctx.eng, self.ctx.keys, self.mm_plan
+            assert ctA.level == ctB.level == self.plan.level
+            if self.plan.batched:
+                items = self._step2_items(ctA, ctB)
+                with span("hemm.step2"):
+                    outs = self._step2(items)
+            else:
+                s1a, s1b = self._step1
+                with span("hemm.step1"):
+                    ctA0, ctB0 = s1a(ctA), s1b(ctB)
+                if self.plan.schedule == "baseline" or \
+                        self.plan.schedule.startswith("sharded"):
+                    inA, inB = ctA0, ctB0
+                else:   # hoist once, reuse across all l Step-2 HLTs per input
+                    dp = self.plan.step2.datapath
+                    with span("hemm.hoist"):
+                        inA = hoist(eng, ctA0, datapath=dp)
+                        inB = hoist(eng, ctB0, datapath=dp)
+                with span("hemm.step2"):
+                    outs = ([run(inA) for run in self._step2[:p.l]]
+                            + [run(inB) for run in self._step2[p.l:]])
+            acc: Optional[Ciphertext] = None
+            with span("hemm.tail"):
+                for k in range(p.l):
+                    prod = eng.rescale(eng.mult(outs[k], outs[p.l + k], keys))
+                    acc = prod if acc is None else eng.add(acc, prod)
+            return acc
 
     def device_bytes(self) -> dict:
         """Compile-time operand bytes per device id, both HLT steps."""
@@ -925,14 +947,16 @@ class HEMMProgram:
     def _step2_items(self, ctA: Ciphertext, ctB: Ciphertext) -> list:
         """Batched Step 1, then the 2·l Step-2 batch items."""
         l = self.mm_plan.l
-        ctA0, ctB0 = self._step1([ctA, ctB])
+        with span("hemm.step1"):
+            ctA0, ctB0 = self._step1([ctA, ctB])
         if self.plan.schedule.startswith("sharded"):
             # the SPMD program hoists internally (limb-local, off the
             # replicated inputs; the fused datapath hoists each unique
             # ciphertext ONCE per rank) — feed the Step-1 cts directly
             return [ctA0] * l + [ctB0] * l
-        hstA, hstB = hoist_batched(self.ctx.eng, [ctA0, ctB0],
-                                   datapath=self.plan.step2.datapath)
+        with span("hemm.hoist"):
+            hstA, hstB = hoist_batched(self.ctx.eng, [ctA0, ctB0],
+                                       datapath=self.plan.step2.datapath)
         return [hstA] * l + [hstB] * l
 
     def hlo(self, ctA: Ciphertext, ctB: Ciphertext) -> str:
@@ -950,52 +974,53 @@ def compile_hemm(ctx: HEContext, plan, *, level: Optional[int] = None,
     reusable HEMMProgram.  ``schedule=None`` / ``rotation_chunk=None`` defer
     to the cost model; ``batched=None`` batches whenever the fused schedule
     is chosen.  Memoized on the context (same plan → same program)."""
-    assert ctx.keys is not None, "HEContext has no keys; call ctx.keygen()"
-    eng = ctx.eng
-    level = eng.params.L if level is None else level
-    nbeta = len(eng.tools.digit_bases(level))
-    if schedule is None:
-        # Step 2 dominates (2·l HLTs) and runs off 2 unique inputs — model
-        # the hoist-dedup term with the aliasing the program will create
-        schedule = select_schedule(
-            eng.params, nbeta=nbeta, headroom=ctx.vmem_headroom,
-            n_model=ctx.n_model, n_ct=ctx.n_ct,
-            d=plan.ds_sigma.d, ctb=2 * plan.l, n_uniq=2)
-    if batched is None:
-        batched = schedule in ("pallas", "sharded", "sharded_xla")
-    batched = batched and schedule != "baseline"
-    memo_key = ("hemm", _StrongKey(plan), schedule, level, rotation_chunk,
-                batched, ctx.verify)
-    hit = ctx._compiled.get(memo_key)
-    if hit is not None:
-        return hit
+    with span("hemm.compile"):
+        assert ctx.keys is not None, "HEContext has no keys; call ctx.keygen()"
+        eng = ctx.eng
+        level = eng.params.L if level is None else level
+        nbeta = len(eng.tools.digit_bases(level))
+        if schedule is None:
+            # Step 2 dominates (2·l HLTs) and runs off 2 unique inputs — model
+            # the hoist-dedup term with the aliasing the program will create
+            schedule = select_schedule(
+                eng.params, nbeta=nbeta, headroom=ctx.vmem_headroom,
+                n_model=ctx.n_model, n_ct=ctx.n_ct,
+                d=plan.ds_sigma.d, ctb=2 * plan.l, n_uniq=2)
+        if batched is None:
+            batched = schedule in ("pallas", "sharded", "sharded_xla")
+        batched = batched and schedule != "baseline"
+        memo_key = ("hemm", _StrongKey(plan), schedule, level, rotation_chunk,
+                    batched, ctx.verify)
+        hit = ctx._compiled.get(memo_key)
+        if hit is not None:
+            return hit
 
-    step2_sets = list(plan.ds_eps) + list(plan.ds_omega)
-    if batched:
-        step1 = compile_hlt(ctx, [plan.ds_sigma, plan.ds_tau], level=level,
-                            schedule=schedule, rotation_chunk=rotation_chunk,
-                            ct_slots=(0, 1))
-        # Step 2 runs 2·l HLTs over TWO unique inputs ([A0]·l + [B0]·l):
-        # the ct_slots hint sizes the hoist-dedup plan numbers and (under
-        # sharded) pre-builds the arena slot tables for the common case.
-        step2 = compile_hlt(ctx, step2_sets, level=level - 1,
-                            schedule=schedule, rotation_chunk=rotation_chunk,
-                            ct_slots=(0,) * plan.l + (1,) * plan.l)
-        s1_plan, s2_plan = step1.plan, step2.plan
-    else:
-        c = lambda ds, lv: compile_hlt(ctx, ds, level=lv, schedule=schedule,
-                                       rotation_chunk=rotation_chunk)
-        step1 = (c(plan.ds_sigma, level), c(plan.ds_tau, level))
-        step2 = tuple(c(ds, level - 1) for ds in step2_sets)
-        s1_plan, s2_plan = step1[0].plan, step2[0].plan
-    prog = HEMMProgram(
-        ctx, plan,
-        HEMMPlan(m=plan.m, l=plan.l, n=plan.n, schedule=schedule, level=level,
-                 batched=batched, step1=s1_plan, step2=s2_plan),
-        step1, step2)
-    _enforce_verify(ctx, prog)
-    ctx._compiled[memo_key] = prog
-    return prog
+        step2_sets = list(plan.ds_eps) + list(plan.ds_omega)
+        if batched:
+            step1 = compile_hlt(ctx, [plan.ds_sigma, plan.ds_tau], level=level,
+                                schedule=schedule, rotation_chunk=rotation_chunk,
+                                ct_slots=(0, 1))
+            # Step 2 runs 2·l HLTs over TWO unique inputs ([A0]·l + [B0]·l):
+            # the ct_slots hint sizes the hoist-dedup plan numbers and (under
+            # sharded) pre-builds the arena slot tables for the common case.
+            step2 = compile_hlt(ctx, step2_sets, level=level - 1,
+                                schedule=schedule, rotation_chunk=rotation_chunk,
+                                ct_slots=(0,) * plan.l + (1,) * plan.l)
+            s1_plan, s2_plan = step1.plan, step2.plan
+        else:
+            c = lambda ds, lv: compile_hlt(ctx, ds, level=lv, schedule=schedule,
+                                           rotation_chunk=rotation_chunk)
+            step1 = (c(plan.ds_sigma, level), c(plan.ds_tau, level))
+            step2 = tuple(c(ds, level - 1) for ds in step2_sets)
+            s1_plan, s2_plan = step1[0].plan, step2[0].plan
+        prog = HEMMProgram(
+            ctx, plan,
+            HEMMPlan(m=plan.m, l=plan.l, n=plan.n, schedule=schedule, level=level,
+                     batched=batched, step1=s1_plan, step2=s2_plan),
+            step1, step2)
+        _enforce_verify(ctx, prog)
+        ctx._compiled[memo_key] = prog
+        return prog
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,7 @@ artifact (no device allocation — inputs are ShapeDtypeStructs).
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
   PYTHONPATH=src python -m repro.launch.dryrun --he set-b --mesh pod
 
-Results land in results/dryrun/<arch>__<shape>__<mesh>.json and are read by
-benchmarks/roofline.py for EXPERIMENTS.md §Roofline.
+Results land in results/dryrun/<arch>__<shape>__<mesh>.json.
 """
 import argparse
 import functools
