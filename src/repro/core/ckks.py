@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import numpy as np
@@ -314,10 +315,8 @@ class CkksEngine:
     def add(self, a: Ciphertext, b: Ciphertext) -> Ciphertext:
         with span("ckks.add"):
             assert a.level == b.level, (a.level, b.level)
-            view = self.main_basis(a.level)
-            return Ciphertext(mm.addmod(a.c0, b.c0, view.moduli),
-                              mm.addmod(a.c1, b.c1, view.moduli),
-                              a.level, max(a.scale, b.scale))
+            c0, c1 = _add_program(self.params, a.level, a.c0, a.c1, b.c0, b.c1)
+            return Ciphertext(c0, c1, a.level, max(a.scale, b.scale))
 
     def sub(self, a: Ciphertext, b: Ciphertext) -> Ciphertext:
         view = self.main_basis(a.level)
@@ -339,19 +338,22 @@ class CkksEngine:
 
     def mult(self, a: Ciphertext, b: Ciphertext, keys: Keys) -> Ciphertext:
         """ct × ct with relinearization (no rescale — call rescale() after,
-        mirroring paper Algorithm 1/2 structure)."""
+        mirroring paper Algorithm 1/2 structure). One compiled program."""
         with span("ckks.mult"):
             assert a.level == b.level
-            ell = a.level
-            view = self.main_basis(ell)
-            d0 = mm.mulmod(a.c0, b.c0, view.moduli)
-            d1 = mm.addmod(mm.mulmod(a.c0, b.c1, view.moduli),
-                           mm.mulmod(a.c1, b.c0, view.moduli), view.moduli)
-            d2 = mm.mulmod(a.c1, b.c1, view.moduli)
-            k0, k1 = self.key_switch(d2, keys.evk_mult, ell)
-            return Ciphertext(mm.addmod(d0, k0, view.moduli),
-                              mm.addmod(d1, k1, view.moduli),
-                              ell, a.scale * b.scale)
+            evk = keys.evk_mult
+            c0, c1 = _mult_program(self.params, self.datapath, a.level,
+                                   a.c0, a.c1, b.c0, b.c1, evk.k0, evk.k1)
+            return Ciphertext(c0, c1, a.level, a.scale * b.scale)
+
+    def _mult_body(self, a0, a1, b0, b1, k0, k1, ell: int):
+        """Tensor product, relinearisation of d2 and the final adds."""
+        q = self.main_basis(ell).moduli
+        d0 = mm.mulmod(a0, b0, q)
+        d1 = mm.addmod(mm.mulmod(a0, b1, q), mm.mulmod(a1, b0, q), q)
+        d2 = mm.mulmod(a1, b1, q)
+        ks0, ks1 = self._key_switch_body(d2, k0, k1, ell)
+        return mm.addmod(d0, ks0, q), mm.addmod(d1, ks1, q)
 
     def rotate(self, ct: Ciphertext, r: int, keys: Keys) -> Ciphertext:
         """Rot(ct, r): circular left rotation of slots by r."""
@@ -367,35 +369,48 @@ class CkksEngine:
     # -- keyswitch (coarse-grained baseline; Fig. 2(A)) ------------------------
 
     def key_switch(self, d, evk: EvalKey, ell: int):
-        """d: (ell+1, N) eval-domain poly under s'; returns (k0, k1) under s."""
+        """d: (ell+1, N) eval-domain poly under s'; returns (k0, k1) under s.
+        One compiled program."""
         with span("ckks.key_switch"):
-            p = self.params
-            bases = self.tools.digit_bases(ell)
-            full = bases[0][2]
-            fview = self.basis(full)
-            acc0 = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
-            acc1 = jnp.zeros_like(acc0)
-            for j, (own, gen, _) in enumerate(bases):
-                dig_eval = d[own[0]: own[-1] + 1]
-                coeff = self._intt(dig_eval, self.basis(own))
-                ext = self.tools.mod_up(coeff, own, gen)
-                ext_eval = self._ntt(ext, self.basis(gen))
-                # assemble digit over full basis (reuse own eval limbs directly)
-                pos = {g: i for i, g in enumerate(full)}
-                xfull = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
-                xfull = xfull.at[np.array([pos[i] for i in own])].set(dig_eval)
-                xfull = xfull.at[np.array([pos[i] for i in gen])].set(ext_eval)
-                rows = np.array(full)
-                acc0 = mm.addmod(acc0, mm.mulmod(xfull, evk.k0[j][rows], fview.moduli),
-                                 fview.moduli)
-                acc1 = mm.addmod(acc1, mm.mulmod(xfull, evk.k1[j][rows], fview.moduli),
-                                 fview.moduli)
-            return self._mod_down_eval(acc0, ell), self._mod_down_eval(acc1, ell)
+            return _key_switch_program(self.params, self.datapath, ell,
+                                       d, evk.k0, evk.k1)
+
+    def _key_switch_body(self, d, k0, k1, ell: int):
+        p = self.params
+        bases = self.tools.digit_bases(ell)
+        full = bases[0][2]
+        fview = self.basis(full)
+        pos = {g: i for i, g in enumerate(full)}
+        rows = np.array(full)
+        # the digits partition d's limbs and the transforms work limb by
+        # limb: one iNTT gives every digit's coefficients, one NTT every
+        # digit's extension
+        coeff = self._intt(d, self.main_basis(ell))
+        exts = [self.tools.mod_up(coeff[own[0]: own[-1] + 1], own, gen)
+                for own, gen, _ in bases]
+        gens = [i for _, gen, _ in bases for i in gen]
+        ext_evals = jnp.split(self._ntt(jnp.concatenate(exts), self.basis(gens)),
+                              np.cumsum([len(e) for e in exts])[:-1])
+        acc0 = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
+        acc1 = jnp.zeros_like(acc0)
+        for j, ((own, gen, _), ext_eval) in enumerate(zip(bases, ext_evals)):
+            dig_eval = d[own[0]: own[-1] + 1]
+            # assemble digit over full basis (reuse own eval limbs directly)
+            xfull = jnp.zeros((len(full), p.N), dtype=jnp.uint32)
+            xfull = xfull.at[np.array([pos[i] for i in own])].set(dig_eval)
+            xfull = xfull.at[np.array([pos[i] for i in gen])].set(ext_eval)
+            acc0 = mm.addmod(acc0, mm.mulmod(xfull, k0[j][rows], fview.moduli),
+                             fview.moduli)
+            acc1 = mm.addmod(acc1, mm.mulmod(xfull, k1[j][rows], fview.moduli),
+                             fview.moduli)
+        ks = self._mod_down_eval(jnp.stack([acc0, acc1]), ell)
+        return ks[0], ks[1]
 
     def _mod_down_eval(self, x_full, ell: int, drop_last: bool = False,
                        datapath: Optional[str] = None):
         """ModDown from Q_ℓ ∪ P back to Q_ℓ (or Q_{ℓ-1} when drop_last — the
-        paper's merged ModDown+Rescale), eval domain in/out.
+        paper's merged ModDown+Rescale), eval domain in/out. x_full is
+        (..., M, N); the fused pallas path takes one (M, N) poly.
 
         datapath overrides the engine knob per call; "pallas" + drop_last
         runs the whole iNTT→BaseConv→NTT→sub→·P⁻¹ tail as two fused
@@ -411,33 +426,78 @@ class CkksEngine:
         Q = tuple(range(ell)) if drop_last else tuple(range(ell + 1))
         nq = ell + 1
         if drop_last:  # fold q_ell into the dropped basis (merged ModDown+Rescale)
-            x_p_eval = jnp.concatenate([x_full[nq:], x_full[ell:ell + 1]], axis=0)
+            x_p_eval = jnp.concatenate([x_full[..., nq:, :],
+                                        x_full[..., ell:ell + 1, :]], axis=-2)
         else:
-            x_p_eval = x_full[nq:]
-        # P-part -> coeff -> baseconv -> eval over Q
-        x_p_coeff = self._intt(x_p_eval, self.basis(P))
-        conv = self.tools.base_conv(x_p_coeff, P, Q)
-        qv = self.basis(Q)
-        conv_eval = self._ntt(conv, qv)
+            x_p_eval = x_full[..., nq:, :]
+        return self._drop_basis(x_full[..., :len(Q), :], x_p_eval, P, Q)
+
+    def _drop_basis(self, x_q, x_p, P: tuple, Q: tuple):
+        """(x − [x]_P)·P⁻¹ over Q, eval domain in and out, from x's residues
+        x_q (..., |Q|, N) over Q and x_p (..., |P|, N) over P. The transforms
+        work limb by limb, so the rows of all leading entries share one iNTT
+        and one NTT."""
+        lead, N = x_p.shape[:-2], x_p.shape[-1]
+        n = math.prod(lead)
+        coeff = self._intt(x_p.reshape(-1, N), self.basis(P * n))
+        conv = jnp.concatenate([self.tools.base_conv(c, P, Q)
+                                for c in jnp.split(coeff, n)])
+        conv_eval = self._ntt(conv, self.basis(Q * n)).reshape(x_q.shape)
+        q = self.basis(Q).moduli
         p_inv = self.tools._moddown_tables(P, Q)
-        return mm.mulmod(mm.submod(x_full[: len(Q)], conv_eval, qv.moduli),
-                         p_inv, qv.moduli)
+        return mm.mulmod(mm.submod(x_q, conv_eval, q), p_inv, q)
 
     # -- rescale ---------------------------------------------------------------
 
     def rescale(self, ct: Ciphertext) -> Ciphertext:
-        """Divide by q_ℓ, dropping one level (eval-domain single-limb path)."""
+        """Divide by q_ℓ, dropping one level (eval-domain single-limb path).
+        One compiled program."""
         with span("ckks.rescale"):
             ell = ct.level
             q_ell = self.ctx.moduli_host[ell]
-            c0 = self._rescale_poly(ct.c0, ell)
-            c1 = self._rescale_poly(ct.c1, ell)
+            c0, c1 = _rescale_program(self.params, self.datapath, ell,
+                                      ct.c0, ct.c1)
             return Ciphertext(c0, c1, ell - 1, ct.scale / q_ell)
 
     def _rescale_poly(self, x, ell: int):
-        last_coeff = self._intt(x[ell:ell + 1], self.basis((ell,)))
-        conv = self.tools.base_conv(last_coeff, (ell,), tuple(range(ell)))
-        qv = self.main_basis(ell - 1)
-        conv_eval = self._ntt(conv, qv)
-        p_inv = self.tools._moddown_tables((ell,), tuple(range(ell)))
-        return mm.mulmod(mm.submod(x[:ell], conv_eval, qv.moduli), p_inv, qv.moduli)
+        """x (..., ell+1, N) -> (..., ell, N): the single-limb ModDown."""
+        return self._drop_basis(x[..., :ell, :], x[..., ell:ell + 1, :],
+                                (ell,), tuple(range(ell)))
+
+
+# ---------------------------------------------------------------------------
+# compiled programs of the homomorphic ops
+# ---------------------------------------------------------------------------
+# One XLA program per (params, datapath, level), shared by every engine with
+# equal params. Limbs and key rows are arguments (keygen and
+# HEContext.invalidate() swap keys without a retrace); the level, and the
+# bases it fixes, are static; scales stay on the host. The bodies carry no
+# span: an annotation inside a traced body fires at trace time only.
+
+
+@functools.lru_cache(maxsize=None)
+def _engine(params: HEParams, datapath: str) -> CkksEngine:
+    """The engine whose methods trace the programs for ``params``."""
+    return CkksEngine(params, datapath)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _mult_program(params, datapath, ell, a0, a1, b0, b1, k0, k1):
+    return _engine(params, datapath)._mult_body(a0, a1, b0, b1, k0, k1, ell)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _key_switch_program(params, datapath, ell, d, k0, k1):
+    return _engine(params, datapath)._key_switch_body(d, k0, k1, ell)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _rescale_program(params, datapath, ell, c0, c1):
+    out = _engine(params, datapath)._rescale_poly(jnp.stack([c0, c1]), ell)
+    return out[0], out[1]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _add_program(params, ell, a0, a1, b0, b1):
+    q = get_context(params).moduli[: ell + 1]
+    return mm.addmod(a0, b0, q), mm.addmod(a1, b1, q)
