@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from collections.abc import Mapping
 from typing import Optional
 
 import numpy as np
@@ -28,6 +29,9 @@ from repro.core.params import HEParams, PrimeContext, get_context
 from repro.core.rns import RnsTools
 from repro.core.spans import span
 from repro.kernels import basechange, ops
+
+#: rotation keys generated per device program by ``CkksEngine.keygen``
+KEYGEN_CHUNK = 16
 
 
 # ---------------------------------------------------------------------------
@@ -63,11 +67,81 @@ class EvalKey:
 
 
 @dataclasses.dataclass
+class KeyTable:
+    """Every rotation key once, stacked: row ``rows[g]`` is the key of
+    galois element g, all β digits over the full basis, each polynomial as
+    fused-kernel tiles (``core/ntt.tile_shape``), the form the fused HLT
+    kernel reads by row index.
+
+    ``mont`` marks the Montgomery form that kernel needs:
+    ``HEContext.key_table`` converts the table to it in place (``to_mont``),
+    once per keygen, so the device never holds a second copy. ``key(row)``
+    returns the standard (β, M, N) form either way. An empty key set keeps
+    one zero row, so the kernel always has a row to index."""
+    k0: jnp.ndarray           # (max(1, S), beta, M, R, C) u32 eval
+    k1: jnp.ndarray
+    rows: dict[int, int]      # galois element -> row
+    q32: jnp.ndarray          # (M, 1) full-basis Montgomery constants
+    qneg: jnp.ndarray
+    r2: jnp.ndarray
+    mont: bool = False
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.k0.nbytes) + int(self.k1.nbytes)
+
+    def key(self, row: int) -> EvalKey:
+        shape = self.k0.shape[1:3] + (-1,)
+        k0 = self.k0[row].reshape(shape)
+        k1 = self.k1[row].reshape(shape)
+        if self.mont:
+            k0 = mm.from_mont(k0, self.q32, self.qneg)
+            k1 = mm.from_mont(k1, self.q32, self.qneg)
+        return EvalKey(k0=k0, k1=k1)
+
+    def to_mont(self) -> None:
+        """Convert the table to the Montgomery domain, in place on the
+        device (the standard-form buffers are donated)."""
+        if not self.mont:
+            self.k0, self.k1 = _table_to_mont(self.k0, self.k1, self.q32,
+                                              self.qneg, self.r2)
+            self.mont = True
+
+
+class _TableKeys(Mapping):
+    """Read-only ``key -> EvalKey`` view of a KeyTable through ``index``
+    (key -> row): keys are sliced out on access, never held."""
+
+    def __init__(self, table: KeyTable, index: dict):
+        self._table, self._index = table, index
+
+    def __getitem__(self, key) -> EvalKey:
+        return self._table.key(self._index[key])
+
+    def __iter__(self):
+        return iter(self._index)
+
+    def __len__(self) -> int:
+        return len(self._index)
+
+
+@dataclasses.dataclass
 class Keys:
     s_eval: jnp.ndarray                 # (M, N) secret over full basis
     evk_mult: EvalKey
-    rot: dict[int, EvalKey]             # step -> key
-    galois: dict[int, EvalKey]          # galois element -> key (same objects)
+    table: KeyTable                     # every rotation key, once
+    steps: dict[int, int]               # rotation step -> galois element
+
+    @property
+    def galois(self) -> Mapping:
+        """galois element -> rotation key (standard form)."""
+        return _TableKeys(self.table, self.table.rows)
+
+    @property
+    def rot(self) -> Mapping:
+        """rotation step -> rotation key (standard form)."""
+        return _TableKeys(self.table, {r: self.table.rows[g]
+                                       for r, g in self.steps.items()})
 
 
 # ---------------------------------------------------------------------------
@@ -224,39 +298,36 @@ class CkksEngine:
             full = list(range(p.num_total))
             s_int = rng.integers(-1, 2, size=p.N).astype(np.int64)
             s_eval = self._small_poly_eval(s_int, full)
-            s2_int = None  # s^2 handled in eval domain below
 
             # s^2 over full basis (eval-domain product)
             view = self.basis(full)
             s2_eval = mm.mulmod(s_eval, s_eval, view.moduli)
 
             evk_mult = self._make_evk(rng, s_eval, s2_eval)
-            rot, galois = {}, {}
+            steps, rows = {}, {}
             for r in rot_steps:
                 g = automorph.galois_elt_rot(r, p.N)
-                if g in galois:
-                    rot[r] = galois[g]
-                    continue
-                s_rot = automorph.apply_eval(s_eval, p.N, g)
-                k = self._make_evk(rng, s_eval, s_rot)
-                rot[r] = k
-                galois[g] = k
-            return Keys(s_eval=s_eval, evk_mult=evk_mult, rot=rot, galois=galois)
+                steps[r] = g
+                rows.setdefault(g, len(rows))
+            table = self._rotation_key_table(rng, s_eval, list(rows))
+            return Keys(s_eval=s_eval, evk_mult=evk_mult,
+                        table=KeyTable(*table, rows=rows,
+                                       q32=view.moduli_u32,
+                                       qneg=view.qneg_inv, r2=view.r2),
+                        steps=steps)
 
-    def _make_evk(self, rng: np.random.Generator, s_eval, sprime_eval) -> EvalKey:
-        """evk_j = (-a_j s + e_j + W_j s', a_j) over the full basis, where
-        W_j = P · [ D̂_j · (D̂_j^{-1} mod D_j) ]  (gadget factor, paper §II-B3)."""
+    def _gadget(self) -> np.ndarray:
+        """(β, M, 1) u32: W_j = P · [ D̂_j · (D̂_j^{-1} mod D_j) ] of each
+        key-switch digit j over the full basis (paper §II-B3)."""
         p = self.params
         full = list(range(p.num_total))
-        view = self.basis(full)
         Pprod = 1
         for i in range(p.num_main, p.num_total):
             Pprod *= self.ctx.moduli_host[i]
         QL = 1
         for i in range(p.num_main):
             QL *= self.ctx.moduli_host[i]
-
-        k0s, k1s = [], []
+        ws = []
         for (st, en) in p.digits_at_level(p.L):
             Dj = 1
             for i in range(st, en):
@@ -264,20 +335,57 @@ class CkksEngine:
             hatDj = QL // Dj
             # NB: D_j is composite — use the general modular inverse, not Fermat.
             w_int = Pprod * hatDj * pow(hatDj % Dj, -1, Dj)
-            w_res = np.array(
-                [w_int % self.ctx.moduli_host[i] for i in full], dtype=np.uint64
-            )[:, None]
+            ws.append([w_int % self.ctx.moduli_host[i] for i in full])
+        return np.array(ws, dtype=np.uint64)[:, :, None].astype(np.uint32)
+
+    def _make_evk(self, rng: np.random.Generator, s_eval, sprime_eval) -> EvalKey:
+        """evk_j = (-a_j s + e_j + W_j s', a_j) over the full basis, where
+        W_j is the gadget factor (``_gadget``)."""
+        p = self.params
+        full = list(range(p.num_total))
+        view = self.basis(full)
+        k0s, k1s = [], []
+        for w_res in self._gadget():
             a = self._uniform_poly(rng, full)
             e_eval = self._small_poly_eval(
                 np.round(rng.normal(0, 3.2, size=p.N)).astype(np.int64), full)
-            w_sp = mm.mulmod(sprime_eval, jnp.asarray(w_res).astype(jnp.uint32),
-                             view.moduli)
+            w_sp = mm.mulmod(sprime_eval, jnp.asarray(w_res), view.moduli)
             k0 = mm.addmod(
                 mm.submod(e_eval, mm.mulmod(a, s_eval, view.moduli), view.moduli),
                 w_sp, view.moduli)
             k0s.append(k0)
             k1s.append(a)
         return EvalKey(k0=jnp.stack(k0s), k1=jnp.stack(k1s))
+
+    def _rotation_key_table(self, rng: np.random.Generator, s_eval, gs):
+        """(k0, k1) tables of the rotation keys of galois elements ``gs``,
+        row by row in that order: the same draws, in the same order, and the
+        same keys as one ``_make_evk(rng, s_eval, ψ_g(s))`` per element,
+        computed ``KEYGEN_CHUNK`` keys per device program and written into
+        the table in place."""
+        p = self.params
+        full = list(range(p.num_total))
+        nbeta, M = len(p.digits_at_level(p.L)), len(full)
+        qs = np.array([self.ctx.moduli_host[i] for i in full],
+                      dtype=np.uint64)[:, None]
+        w = jnp.asarray(self._gadget())
+        shape = (max(1, len(gs)), nbeta, M) + ntt.tile_shape(p.N)
+        k0 = jnp.zeros(shape, jnp.uint32)
+        k1 = jnp.zeros(shape, jnp.uint32)
+        for start in range(0, len(gs), KEYGEN_CHUNK):
+            part = gs[start: start + KEYGEN_CHUNK]
+            a = np.empty((len(part), nbeta, M, p.N), np.uint32)
+            e = np.empty((len(part), nbeta, M, p.N), np.uint32)
+            for i in range(len(part)):
+                for j in range(nbeta):      # _make_evk's draws, in order
+                    a[i, j] = rng.integers(0, qs, size=(M, p.N))
+                    e[i, j] = self._residues_all(np.round(rng.normal(
+                        0, 3.2, size=p.N)).astype(np.int64), full)
+            perms = np.stack([automorph.eval_perm(p.N, g) for g in part])
+            k0, k1 = _rotation_keys_program(
+                p, self.datapath, k0, k1, np.int32(start), s_eval, w,
+                jnp.asarray(a), jnp.asarray(e), jnp.asarray(perms))
+        return k0, k1
 
     def _uniform_poly(self, rng: np.random.Generator, idx) -> jnp.ndarray:
         qs = np.array([self.ctx.moduli_host[i] for i in idx], dtype=np.uint64)[:, None]
@@ -359,7 +467,7 @@ class CkksEngine:
         """Rot(ct, r): circular left rotation of slots by r."""
         p = self.params
         g = automorph.galois_elt_rot(r, p.N)
-        key = keys.galois.get(g) or keys.rot[r]
+        key = keys.galois[g]
         c0p = automorph.apply_eval(ct.c0, p.N, g)
         c1p = automorph.apply_eval(ct.c1, p.N, g)
         k0, k1 = self.key_switch(c1p, key, ct.level)
@@ -495,6 +603,36 @@ def _key_switch_program(params, datapath, ell, d, k0, k1):
 def _rescale_program(params, datapath, ell, c0, c1):
     out = _engine(params, datapath)._rescale_poly(jnp.stack([c0, c1]), ell)
     return out[0], out[1]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+def _rotation_keys_program(params, datapath, k0, k1, start, s_eval, w, a, e,
+                           perms):
+    """Rows ``start ..`` of the tiled rotation-key tables (donated, written
+    in place): for each key i and digit j, k1 = a and k0 = e − a·s + W_j·ψ(s),
+    with ψ the key's eval-domain permutation ``perms[i]``."""
+    eng = _engine(params, datapath)
+    view = eng.basis(list(range(params.num_total)))
+    q = view.moduli
+    K, nbeta, M, N = a.shape
+    e_eval = jax.vmap(lambda x: eng._ntt(x, view))(
+        e.reshape(K * nbeta, M, N)).reshape(K, nbeta, M, N)
+    s_rot = jnp.moveaxis(s_eval[:, perms], 1, 0)[:, None]    # (K, 1, M, N)
+    w_sp = mm.mulmod(s_rot, w, q)
+    new0 = mm.addmod(mm.submod(e_eval, mm.mulmod(a, s_eval, q), q), w_sp, q)
+    zero = jnp.zeros((), start.dtype)
+    at = (start, zero, zero, zero, zero)
+    tile = lambda x: x.reshape(x.shape[:3] + k0.shape[3:])
+    return (jax.lax.dynamic_update_slice(k0, tile(new0), at),
+            jax.lax.dynamic_update_slice(k1, tile(a), at))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _table_to_mont(k0, k1, q32, qneg, r2):
+    """Both (S, β, M, R, C) key tables to the Montgomery domain, in place."""
+    c = lambda x: x[:, :, None]                  # (M, 1) -> (M, 1, 1)
+    return (mm.to_mont(k0, c(q32), c(qneg), c(r2)),
+            mm.to_mont(k1, c(q32), c(qneg), c(r2)))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))

@@ -38,8 +38,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import hlt as hlt_mod, hlt_dist
-from repro.core.ckks import Ciphertext, CkksEngine, Keys
+from repro.core import automorph, hlt as hlt_mod, hlt_dist
+from repro.core.ckks import Ciphertext, CkksEngine, Keys, KeyTable
 from repro.core.costmodel import (VMEM_HEADROOM, hlt_hoist_bytes,
                                   hlt_stage_costs, pick_rotation_chunk,
                                   select_chain_schedules, select_schedule,
@@ -77,18 +77,21 @@ class _StrongKey:
 class OperandArena:
     """Device-resident operand store: ONE slot per unique operand group.
 
-    Entries are keyed by (kind, owning object identity, compile point), e.g.
-    the Montgomery kernel operands of one DiagSet at one (level, β, d_pad).
-    Compiling the same DiagSet into many programs (hemm Step-1, every block-MM
-    tile stage, …) reuses the same device buffers.
+    Entries are keyed by (kind, owning objects' identities, compile point),
+    e.g. the context's rotation-key table (owned by the keys), or the
+    stacked kernel operands of one launch's unique DiagSets at one
+    (level, d_pad). Compiling the same launch into many programs reuses the
+    same device buffers.
     """
 
     def __init__(self):
         self._entries: dict = {}
 
     def slot(self, kind: str, obj, extra: tuple, builder):
-        """Return ``(slot_id, value)`` for the key, building it on miss."""
-        key = (kind, _StrongKey(obj), extra)
+        """Return ``(slot_id, value)`` for the key, building it on miss.
+        ``obj`` is the owning object, or a tuple of owning objects."""
+        owners = obj if isinstance(obj, tuple) else (obj,)
+        key = (kind, tuple(_StrongKey(o) for o in owners), extra)
         hit = self._entries.get(key)
         if hit is None:
             hit = (len(self._entries), builder())
@@ -211,6 +214,22 @@ class HEContext:
         self._compiled.clear()
         self._generation += 1
 
+    def key_table(self) -> KeyTable:
+        """The rotation keys as the fused kernel reads them: the keys' one
+        table, converted to the Montgomery domain in place the first time
+        any context asks (span ``hlt.keytable``), and held by the arena.
+        Every launch at every level reads it by row index; nothing copies
+        a key per diagonal."""
+        table = self.keys.table
+
+        def build():
+            if not table.mont:
+                with span("hlt.keytable"):
+                    table.to_mont()
+                    jax.block_until_ready((table.k0, table.k1))
+            return table
+        return self.arena.slot("key_table", self.keys, (), build)[1]
+
     def _check_generation(self, gen: int) -> None:
         if gen != self._generation:
             raise RuntimeError(
@@ -221,7 +240,10 @@ class HEContext:
     # -- jitted pipelines (merged ModDown+Rescale included) ------------------
 
     def _pallas_pipeline(self, level: int, chunk: int, kind: str):
-        """Jitted fused-kernel pipeline; kind = "single" | "indexed".
+        """Jitted fused-kernel pipeline; kind = "single" | "indexed". Both
+        take the hoisting product(s), then the key table (k0, k1) and the
+        launch's operands (u_m, perms, is_id, key_idx); "indexed" takes the
+        ct and diagonal slot vectors last.
 
         ``ctx.datapath`` picks the merged-ModDown lowering: "pallas" routes
         it through the fused base-change kernel, "xla" keeps the scan
@@ -234,27 +256,25 @@ class HEContext:
         from repro.kernels import ops
         eng = self.eng
         dp = self.datapath
-        full = eng.tools.digit_bases(level)[0][2]
-        view = eng.basis(full)
+        rows = hlt_mod.key_rows(eng, level)
+        view = eng.basis(rows)
         q32, qneg = view.moduli_u32, view.qneg_inv
 
-        def single(digits, c0e, c1e, u_m, rk0_m, rk1_m, perms, is_id):
-            a0, a1 = ops.fused_hlt(digits, c0e, c1e, u_m, rk0_m, rk1_m,
-                                   perms, is_id, q32, qneg, chunk=chunk)
-            return (eng._mod_down_eval(a0, level, drop_last=True,
-                                       datapath=dp),
-                    eng._mod_down_eval(a1, level, drop_last=True,
-                                       datapath=dp))
-
-        def indexed(digits, c0e, c1e, u_m, rk0_m, rk1_m, perms, is_id,
+        def indexed(digits, c0e, c1e, k0, k1, u_m, perms, is_id, key_idx,
                     ct_slots, diag_slots):
             a0, a1 = ops.fused_hlt_indexed(
-                digits, c0e, c1e, u_m, rk0_m, rk1_m, perms, is_id,
-                ct_slots, diag_slots, q32, qneg, chunk=chunk)
+                digits, c0e, c1e, u_m, k0, k1, key_idx, jnp.asarray(rows),
+                perms, is_id, ct_slots, diag_slots, q32, qneg, chunk=chunk)
             down = jax.vmap(
                 lambda a: eng._mod_down_eval(a, level, drop_last=True,
                                              datapath=dp))
             return down(a0), down(a1)
+
+        def single(digits, c0e, c1e, *operands):
+            zero = jnp.zeros((1,), jnp.int32)
+            c0, c1 = indexed(digits[None], c0e[None], c1e[None], *operands,
+                             zero, zero)
+            return c0[0], c1[0]
 
         fn = jax.jit(single if kind == "single" else indexed)
         self._jit[key] = fn
@@ -334,14 +354,22 @@ class HLTPlan:
     padding rotations are identity+zero-diagonal and contribute nothing).
 
     Operand-dedup fields: ``diag_slots`` maps batch index -> unique
-    diagonal-set arena slot (``n_diag_slots`` unique); ``ct_slots`` is the
+    diagonal-set slot (``n_diag_slots`` unique); ``ct_slots`` is the
     compile-time input-aliasing hint (batch index -> unique input
     ciphertext, ``None`` = unknown until call time) and ``n_ct_slots`` its
     unique count — the number of hoisting products the execution stores
-    (sharded: hoists per rank).  ``operand_bytes`` / ``operand_bytes_naive``
-    are the key+diagonal bytes after / before slot dedup, and
+    (sharded: hoists per rank).  ``operand_bytes`` = ``key_bytes`` +
+    ``diag_bytes`` is what one execution reads its operands from: under
+    ``pallas`` the key-table rows of its distinct rotation keys (the digits
+    and limb rows of this level, whatever the padding) plus its own stacked
+    diagonals, perms, is_id and key rows; under ``sharded`` the per-diagonal
+    key copies plus the diagonals. ``operand_bytes_naive`` is what B-fold
+    stacking with a key copy per rotation slot would allocate, and
     ``hoist_bytes`` / ``hoist_bytes_naive`` the same for hoisting products
     (``sharded_xla`` re-hoists per element, so there they are equal).
+    ``slots`` counts the rotation slots one execution launches (batch ×
+    ``d_pad``) and ``key_rotations`` those that read a rotation key (real,
+    non-identity diagonals); ``slots - rotations`` are padding.
 
     Execution-shape fields: ``chunk`` is the rotation chunk the fused kernel
     keeps resident per grid step (the cost model's VMEM-budget pick — under
@@ -364,8 +392,8 @@ class HLTPlan:
     diag_slots: tuple                   # batch index -> unique operand slot
     n_diag_slots: int                   # == number of UNIQUE diagonal sets
     rotations: int                      # total real rotations per execution
-    operand_bytes: int                  # deduped key/diag operand bytes
-    operand_bytes_naive: int            # what B-fold stacking would allocate
+    operand_bytes: int                  # key_bytes + diag_bytes
+    operand_bytes_naive: int            # B-fold stacking, a key per slot
     stage_costs: dict                   # per-stage byte/rotation counts
     collective_bytes: int = 0           # predicted cross-device bytes / exec
     n_model: int = 1                    # limb-sharding ways (mesh `model`)
@@ -375,6 +403,17 @@ class HLTPlan:
     n_ct_slots: Optional[int] = None    # unique hoisting products stored
     hoist_bytes: int = 0                # hoisting-product bytes after dedup
     hoist_bytes_naive: int = 0          # per-element (no-dedup) hoist bytes
+    key_bytes: int = 0                  # rotation-key bytes read
+    diag_bytes: int = 0                 # diagonal/perm/slot operand bytes
+    slots: int = 0                      # rotation slots launched (B·d_pad)
+    key_rotations: int = 0              # slots that read a rotation key
+
+    def launch_stats(self) -> dict:
+        """The ``hlt.launch`` span's metadata: what one execution
+        launches and reads (profile readers sum it per product)."""
+        return dict(slots=self.slots, rotations=self.key_rotations,
+                    padding=self.slots - self.rotations,
+                    key_bytes=self.key_bytes, diag_bytes=self.diag_bytes)
 
     @property
     def dedup_factor(self) -> float:
@@ -384,6 +423,16 @@ class HLTPlan:
 
 def _operand_nbytes(ops_tuple) -> int:
     return sum(int(a.nbytes) for a in ops_tuple)
+
+
+def _held_bytes(arrays) -> dict:
+    """Bytes per device id of distinct arrays (a shared one counts once)."""
+    held: dict = {}
+    for arr in {id(a): a for a in arrays}.values():
+        for shard in arr.addressable_shards:
+            held[shard.device.id] = (held.get(shard.device.id, 0)
+                                     + shard.data.nbytes)
+    return held
 
 
 def _dedup_by_identity(items):
@@ -492,63 +541,76 @@ def compile_hlt(ctx: HEContext, diags: Union[DiagSet, Sequence[DiagSet]], *,
         chunk = max(1, min(rotation_chunk, d_max))
     d_pad = -(-d_max // chunk) * chunk
 
-    # unique-operand slots: one arena entry per distinct DiagSet
+    # unique diagonal sets: each stored once per launch
     uniq, slots = _dedup_by_identity(diag_list)
 
     ctb = batch if batch is not None else 1
     operands = None
     sharded_tabs = None
     slot_tables = None
-    if schedule == "pallas" or sharded:
+    m_ext = len(eng.tools.digit_bases(level)[0][2])
+    key_unit = 2 * nbeta * m_ext * eng.params.N * 4   # one key at this level
+    keyed = [sum(1 for z in ds.zs if z != 0) for ds in diag_list]
+    key_bytes = diag_bytes = naive = 0
+    if schedule == "pallas":
+        # one stacked operand set per launch (its unique DiagSets), arena
+        # owned and shared by every program that compiles the same launch;
+        # the keys stay in the context's one table, read by row index
+        table = ctx.key_table()
+        _, launch = ctx.arena.slot(
+            "hlt_operands", tuple(uniq), (level, d_pad),
+            lambda: hlt_mod._build_pallas_operands(eng, uniq, table, level,
+                                                   d_pad))
+        operands = (table.k0, table.k1) + launch
+        galois = {automorph.galois_elt_rot(z, eng.params.N)
+                  for ds in uniq for z in ds.zs if z != 0}
+        key_bytes = len(galois) * key_unit
+        diag_bytes = _operand_nbytes(launch)
+        naive = ctb * (d_pad * key_unit + diag_bytes // len(uniq))
+    elif sharded:
         per = [ctx.arena.slot(
-                   "pallas_operands", ds, (level, nbeta, d_pad),
-                   lambda ds=ds: hlt_mod._build_pallas_operands(
+                   "sharded_operands", ds, (level, nbeta, d_pad),
+                   lambda ds=ds: hlt_mod._build_sharded_operands(
                        eng, ds, ctx.keys, level, nbeta, d_pad))[1]
                for ds in uniq]
-        if sharded:
-            # one stacked-and-limb-padded operand set per UNIQUE DiagSet;
-            # the SPMD program gathers by slot (same dedup as the fused
-            # kernel).  DistTables-style constants live in the arena, keyed
-            # like every other operand and dropped by ctx.invalidate().
-            def _build_tabs():
-                t = hlt_dist.build_shard_tables(eng.params, level,
-                                                ctx.n_model)
-                arrays, _ = hlt_dist.place_operands(
-                    t, ctx.rules, hlt_dist.shard_operand_arrays(t), ())
-                return (t, arrays)
-            _, sharded_tabs = ctx.arena.slot(
-                "sharded_tables", eng, (level, ctx.n_model), _build_tabs)
-            m_pad = sharded_tabs[0].M_pad
-            stacked = [jnp.stack([p[i] for p in per]) for i in range(5)]
-            pad = m_pad - stacked[0].shape[2]
-            if pad:
-                u, rk0, rk1 = stacked[:3]
-                stacked[0] = jnp.pad(u, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                stacked[1] = jnp.pad(rk0, ((0, 0), (0, 0), (0, 0), (0, pad),
-                                           (0, 0)))
-                stacked[2] = jnp.pad(rk1, ((0, 0), (0, 0), (0, 0), (0, pad),
-                                           (0, 0)))
-            _, operands = hlt_dist.place_operands(
-                sharded_tabs[0], ctx.rules, {}, tuple(stacked))
-            # batch-index -> slot tables, padded to the ct-axis multiple,
-            # arena-owned like every other operand (hlt_dist.build_slot_tables)
-            b_pad = -(-ctb // max(1, ctx.n_ct)) * max(1, ctx.n_ct)
-            _, slot_tables = ctx.arena.slot(
-                "sharded_slot_tables", eng,
-                (level, tuple(slots), ct_slots, b_pad),
-                lambda: hlt_dist.build_slot_tables(slots, ct_slots, b_pad))
-        elif batch is None:
-            operands = per[0]
-        else:
-            operands = tuple(jnp.stack([p[i] for p in per]) for i in range(5))
+        # one stacked-and-limb-padded operand set per UNIQUE DiagSet;
+        # the SPMD program gathers by slot (same dedup as the fused
+        # kernel).  DistTables-style constants live in the arena, keyed
+        # like every other operand and dropped by ctx.invalidate().
+        def _build_tabs():
+            t = hlt_dist.build_shard_tables(eng.params, level,
+                                            ctx.n_model)
+            arrays, _ = hlt_dist.place_operands(
+                t, ctx.rules, hlt_dist.shard_operand_arrays(t), ())
+            return (t, arrays)
+        _, sharded_tabs = ctx.arena.slot(
+            "sharded_tables", eng, (level, ctx.n_model), _build_tabs)
+        m_pad = sharded_tabs[0].M_pad
+        stacked = [jnp.stack([p[i] for p in per]) for i in range(5)]
+        pad = m_pad - stacked[0].shape[2]
+        if pad:
+            u, rk0, rk1 = stacked[:3]
+            stacked[0] = jnp.pad(u, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            stacked[1] = jnp.pad(rk0, ((0, 0), (0, 0), (0, 0), (0, pad),
+                                       (0, 0)))
+            stacked[2] = jnp.pad(rk1, ((0, 0), (0, 0), (0, 0), (0, pad),
+                                       (0, 0)))
+        _, operands = hlt_dist.place_operands(
+            sharded_tabs[0], ctx.rules, {}, tuple(stacked))
+        # batch-index -> slot tables, padded to the ct-axis multiple,
+        # arena-owned like every other operand (hlt_dist.build_slot_tables)
+        b_pad = -(-ctb // max(1, ctx.n_ct)) * max(1, ctx.n_ct)
+        _, slot_tables = ctx.arena.slot(
+            "sharded_slot_tables", eng,
+            (level, tuple(slots), ct_slots, b_pad),
+            lambda: hlt_dist.build_slot_tables(slots, ct_slots, b_pad))
+        key_bytes = _operand_nbytes(operands[1:3])
+        diag_bytes = _operand_nbytes(operands) - key_bytes
+        naive = (key_bytes + diag_bytes) // len(uniq) * len(diag_list)
 
-    op_bytes = _operand_nbytes(operands) if operands is not None else 0
-    naive = (op_bytes if batch is None else
-             op_bytes // max(1, len(uniq)) * len(diag_list))
     # hoisting-product accounting: one product per UNIQUE input ciphertext
     # (the ct-slot dedup), except sharded_xla which re-hoists per element
     # and baseline which never hoists.  Without a hint, assume all-distinct.
-    m_ext = len(eng.tools.digit_bases(level)[0][2])
     h_unit = int(hlt_hoist_bytes(eng.params, nbeta=nbeta, n_limbs_ext=m_ext))
     n_ct_slots = None if ct_slots is None else len(set(ct_slots))
     n_hoist = ctb if (n_ct_slots is None or schedule == "sharded_xla") \
@@ -558,7 +620,7 @@ def compile_hlt(ctx: HEContext, diags: Union[DiagSet, Sequence[DiagSet]], *,
         level=level, batch=batch, nbeta=nbeta, chunk=chunk,
         d=d_list, d_pad=d_pad, diag_slots=tuple(slots),
         n_diag_slots=len(uniq), rotations=sum(d_list),
-        operand_bytes=op_bytes, operand_bytes_naive=naive,
+        operand_bytes=key_bytes + diag_bytes, operand_bytes_naive=naive,
         stage_costs=hlt_stage_costs(
             eng.params, d=d_max, d_pad=d_pad, nbeta=nbeta, chunk=chunk,
             n_limbs_ext=m_ext,
@@ -573,7 +635,9 @@ def compile_hlt(ctx: HEContext, diags: Union[DiagSet, Sequence[DiagSet]], *,
         vmem_headroom=ctx.vmem_headroom,
         ct_slots=ct_slots, n_ct_slots=n_ct_slots,
         hoist_bytes=0 if schedule == "baseline" else h_unit * n_hoist,
-        hoist_bytes_naive=0 if schedule == "baseline" else h_unit * ctb)
+        hoist_bytes_naive=0 if schedule == "baseline" else h_unit * ctb,
+        key_bytes=key_bytes, diag_bytes=diag_bytes,
+        slots=ctb * d_pad, key_rotations=sum(keyed))
     run = CompiledHLT(ctx, plan, tuple(diag_list), tuple(uniq), operands,
                       sharded_tabs=sharded_tabs, slot_tables=slot_tables)
     _enforce_verify(ctx, run)
@@ -603,17 +667,15 @@ class CompiledHLT:
         self._gen = ctx._generation
 
     def device_bytes(self) -> dict:
-        """Bytes of this program's compile-time operands (rotation keys,
-        diagonals, sharded tables) resident on each device, by device id."""
-        leaves = jax.tree_util.tree_leaves(
+        """Bytes of this program's compile-time operands (the rotation-key
+        table it reads, diagonals, sharded tables) resident on each device,
+        by device id."""
+        return _held_bytes(self._arrays())
+
+    def _arrays(self) -> list:
+        return jax.tree_util.tree_leaves(
             (self._operands, None if self._sharded is None
              else self._sharded[1]))
-        held: dict = {}
-        for arr in leaves:
-            for shard in arr.addressable_shards:
-                held[shard.device.id] = (held.get(shard.device.id, 0)
-                                         + shard.data.nbytes)
-        return held
 
     # -- helpers -------------------------------------------------------------
 
@@ -681,13 +743,8 @@ class CompiledHLT:
             with span("hlt.launch"):
                 return hlt_mod._hlt_mo(eng, hst, ds, ctx.keys, plan.chunk,
                                        ctx._jit)
-        if operands is None:            # single-DiagSet operands from arena
-            operands = ctx.arena.slot(
-                "pallas_operands", ds, (plan.level, plan.nbeta, plan.d_pad),
-                lambda: hlt_mod._build_pallas_operands(
-                    eng, ds, ctx.keys, plan.level, plan.nbeta, plan.d_pad))[1]
         fn = ctx._pallas_pipeline(plan.level, plan.chunk, "single")
-        with span("hlt.launch"):
+        with span("hlt.launch", **plan.launch_stats()):
             c0, c1 = fn(hst.digits, hst.c0_ext, hst.c1_ext, *operands)
         with span("hlt.finish"):
             return self._finish(c0, c1, hst.scale, ds)
@@ -772,7 +829,7 @@ class CompiledHLT:
         fn = ctx._sharded_pipeline(tabs, plan.d_pad, plan.nbeta,
                                    self._datapath, plan.chunk, layout,
                                    plan.datapath)
-        with span("hlt.launch"):
+        with span("hlt.launch", **plan.launch_stats()):
             out0, out1 = fn(args)
         lvl = plan.level
         with span("hlt.finish"):
@@ -815,7 +872,7 @@ class CompiledHLT:
     def _run_batched_pallas(self, items) -> list:
         with span("hlt.hoist"):
             fn, args, hoisted, ct_slots = self._batched_pallas_args(items)
-        with span("hlt.launch"):
+        with span("hlt.launch", **self.plan.launch_stats()):
             c0b, c1b = fn(*args)
         with span("hlt.finish"):
             return [self._finish(c0b[b], c1b[b], hoisted[ct_slots[b]].scale, ds)
@@ -935,14 +992,11 @@ class HEMMProgram:
             return acc
 
     def device_bytes(self) -> dict:
-        """Compile-time operand bytes per device id, both HLT steps."""
-        held: dict = {}
+        """Compile-time operand bytes per device id, both HLT steps (the
+        key table they share counted once)."""
         steps = (self._step1, self._step2) if self.plan.batched else \
             tuple(self._step1) + tuple(self._step2)
-        for step in steps:
-            for dev, n in step.device_bytes().items():
-                held[dev] = held.get(dev, 0) + n
-        return held
+        return _held_bytes([a for step in steps for a in step._arrays()])
 
     def _step2_items(self, ctA: Ciphertext, ctB: Ciphertext) -> list:
         """Batched Step 1, then the 2·l Step-2 batch items."""

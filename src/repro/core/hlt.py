@@ -18,11 +18,12 @@ DESIGN.md §2 tabulates what each one fuses):
 
 * ``pallas``    — the same limb-outer schedule driven through the fused
   Automorph→KeyIP→DiagIP Pallas kernel (kernels/fused_hlt.py) on the u32
-  Montgomery datapath: rotation keys and diagonal plaintexts are converted to
-  the Montgomery domain once per (level, DiagSet), d is padded up to a
-  rotation-chunk multiple with zero-diagonal identity entries, and the chunk
-  defaults to the cost model's VMEM budget (core/costmodel.py
-  pick_rotation_chunk). Bit-exact vs ``mo``/``hoisted``.
+  Montgomery datapath: the diagonal plaintexts are converted to the
+  Montgomery domain once per launch, the rotation keys once per keygen into
+  the context's one key table, which the kernel reads by row index; d is
+  padded up to a rotation-chunk multiple with zero-diagonal identity
+  entries, and the chunk defaults to the cost model's VMEM budget
+  (core/costmodel.py pick_rotation_chunk). Bit-exact vs ``mo``/``hoisted``.
 
 * ``sharded``   — the multi-device shard_map program (core/hlt_dist.py):
   limbs over the mesh ``model`` axis, the ciphertext batch over
@@ -66,8 +67,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import automorph, modmath as mm
-from repro.core.ckks import Ciphertext, CkksEngine, Keys, Plaintext
-from repro.kernels import basechange, ops
+from repro.core.ckks import Ciphertext, CkksEngine, Keys, KeyTable, Plaintext
+from repro.kernels import basechange, common, ops
 
 
 @dataclasses.dataclass
@@ -469,16 +470,55 @@ def _reduce_add(x, q):
 # ---------------------------------------------------------------------------
 
 
-def _build_pallas_operands(eng: CkksEngine, diags: DiagSet, keys: Keys,
-                           level: int, nbeta: int, d_pad: int):
-    """Montgomery-domain kernel operands for one DiagSet, padded to d_pad
-    rotations: (u_m, rk0_m, rk1_m, perms, is_id).  PURE — caching/ownership
-    lives in the HEContext operand arena (core/compile.py), one slot per
-    unique (DiagSet, level, β, d_pad).
+def key_rows(eng: CkksEngine, level: int) -> np.ndarray:
+    """(M_ext,) i32: the key-table limb row of each limb of the extended
+    basis at ``level`` (the table holds the full basis)."""
+    return np.asarray(eng.tools.digit_bases(level)[0][2], np.int32)
 
-    Padding entries are identity rotations (perm = arange) with zero diagonal
-    and is_id=1, so they bypass KeyIP and contribute exactly zero to DiagIP.
+
+def _build_pallas_operands(eng: CkksEngine, diag_list, table: KeyTable,
+                           level: int, d_pad: int):
+    """Kernel operands of one launch over the UNIQUE DiagSets ``diag_list``,
+    each padded to ``d_pad`` rotations and stacked over the sets:
+    (u_m, perms, is_id, key_idx). PURE — ownership lives in the HEContext
+    operand arena (core/compile.py), one entry per launch.
+
+    u_m (S, d_pad, M, R, C) holds the Montgomery-domain diagonals as kernel
+    tiles; key_idx (S, d_pad) i32 is each rotation's row of the context's
+    rotation-key ``table`` — no key is copied here. Padding entries are
+    identity rotations (perm = arange) with zero diagonal and is_id=1, so
+    they bypass KeyIP, read no key (row 0 stands in) and contribute exactly
+    zero to DiagIP.
     """
+    p = eng.params
+    rows = key_rows(eng, level)
+    view = eng.basis(rows)
+    q32, qneg, r2 = view.moduli_u32, view.qneg_inv, view.r2
+    M = len(rows)
+    us, perms, is_id, kidx = [], [], [], []
+    for ds in diag_list:
+        pad = d_pad - ds.d
+        u = mm.to_mont(ds.pt[:, rows], q32, qneg, r2)        # (d, M, N)
+        us.append(common.tiles(jnp.concatenate(
+            [u, jnp.zeros((pad, M, p.N), jnp.uint32)]) if pad else u))
+        perms.append(np.concatenate(
+            [_perm_table(eng, ds.zs),
+             np.tile(np.arange(p.N), (pad, 1))]).astype(np.int32))
+        is_id.append([1 if z == 0 else 0 for z in ds.zs] + [1] * pad)
+        kidx.append([0 if z == 0 else
+                     table.rows[automorph.galois_elt_rot(z, p.N)]
+                     for z in ds.zs] + [0] * pad)
+    return (jnp.stack(us), jnp.asarray(np.stack(perms)),
+            jnp.asarray(np.array(is_id, np.int32)[..., None]),
+            jnp.asarray(np.array(kidx, np.int32)))
+
+
+def _build_sharded_operands(eng: CkksEngine, diags: DiagSet, keys: Keys,
+                            level: int, nbeta: int, d_pad: int):
+    """Montgomery-domain operands of one DiagSet for the ``sharded``
+    schedule, which keeps a rotation key copy per diagonal: (u_m, rk0_m,
+    rk1_m, perms, is_id), padded to d_pad rotations like
+    ``_build_pallas_operands``."""
     p = eng.params
     full = eng.tools.digit_bases(level)[0][2]
     rows = np.asarray(full)

@@ -785,9 +785,14 @@ def make_sharded_hlt_fn(tabs: ShardTables, rules, *, d_pad: int, nbeta: int,
         c0e = mm.montmul(a["c0u"], t["p_raise_m"], q, qn)
         c1e = mm.montmul(a["c1u"], t["p_raise_m"], q, qn)
         # ---- fused rotation loop on this rank's limb-row shard ----
+        # (a key copy per diagonal, read as a table of S·d_pad keys)
+        S, d, nb, m_loc, n = a["rk0"].shape
         acc0, acc1 = ops.fused_hlt_indexed(
-            digits, c0e, c1e, a["u"], a["rk0"], a["rk1"], a["perms"],
-            a["is_id"], a["ct_slots"], a["slots"], q, qn, chunk=kchunk)
+            digits, c0e, c1e, a["u"], a["rk0"].reshape(S * d, nb, m_loc, n),
+            a["rk1"].reshape(S * d, nb, m_loc, n),
+            jnp.arange(S * d, dtype=jnp.int32).reshape(S, d),
+            jnp.arange(m_loc, dtype=jnp.int32), a["perms"], a["is_id"],
+            a["ct_slots"], a["slots"], q, qn, chunk=kchunk)
         mod_down = (make_mod_down_fused if fused_stages
                     else make_mod_down)(t, q, qn)
         return mod_down(acc0), mod_down(acc1)

@@ -17,8 +17,12 @@ SPANS = {
     "hemm.step2": "Step 2: the epsilon/omega CompiledHLT call(s)",
     "hemm.tail": "the l-fold Mult, relinearise, Rescale and Add accumulation",
     "hlt.hoist": "CompiledHLT: hoist (or pack) the inputs of one launch",
-    "hlt.launch": "CompiledHLT: the jitted HLT pipeline call",
+    "hlt.launch": "CompiledHLT: the jitted HLT pipeline call; metadata "
+                  "slots, rotations, padding, key_bytes, diag_bytes "
+                  "(HLTPlan.launch_stats)",
     "hlt.finish": "CompiledHLT: slice the launch's outputs into ciphertexts",
+    "hlt.keytable": "HEContext.key_table: the rotation-key table to the "
+                    "Montgomery domain, in place, once per keygen",
     "ckks.keygen": "CkksEngine.keygen: secret, relinearisation, rotation keys",
     "ckks.mult": "CkksEngine.mult: tensor product and relinearisation",
     "ckks.key_switch": "CkksEngine.key_switch: hybrid key switch",
@@ -27,8 +31,10 @@ SPANS = {
 }
 
 
-def span(name: str) -> jax.profiler.TraceAnnotation:
-    """The host span ``name``, which must be one of :data:`SPANS`."""
+def span(name: str, **metadata) -> jax.profiler.TraceAnnotation:
+    """The host span ``name``, which must be one of :data:`SPANS`;
+    ``metadata`` (whole numbers) becomes the event's stats in the
+    profile."""
     if name not in SPANS:
         raise KeyError(f"unknown span {name!r}")
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **metadata)

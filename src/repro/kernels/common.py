@@ -24,7 +24,8 @@ SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def tiles(x):
-    """(..., N) -> (..., R, C) kernel tiles (row-major, free in XLA)."""
+    """(..., N) -> (..., R, C) kernel tiles (row-major; on the chip a
+    layout copy, so large resident operands are stored tiled)."""
     R, C = core_ntt.tile_shape(x.shape[-1])
     return x.reshape(x.shape[:-1] + (R, C))
 

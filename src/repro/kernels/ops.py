@@ -47,11 +47,13 @@ def fused_hlt_batched(digits, c0e, c1e, u_mont, rk0, rk1, perms, is_id, q32,
                                     interpret=_interp())
 
 
-def fused_hlt_indexed(digits, c0e, c1e, u_mont, rk0, rk1, perms, is_id,
-                      ct_slots, diag_slots, q32, qneg, chunk: int = 8):
-    return _fused.fused_hlt_indexed(digits, c0e, c1e, u_mont, rk0, rk1, perms,
-                                    is_id, ct_slots, diag_slots, q32, qneg,
-                                    chunk=chunk, interpret=_interp())
+def fused_hlt_indexed(digits, c0e, c1e, u_mont, rk0, rk1, key_idx, key_rows,
+                      perms, is_id, ct_slots, diag_slots, q32, qneg,
+                      chunk: int = 8):
+    return _fused.fused_hlt_indexed(digits, c0e, c1e, u_mont, rk0, rk1,
+                                    key_idx, key_rows, perms, is_id, ct_slots,
+                                    diag_slots, q32, qneg, chunk=chunk,
+                                    interpret=_interp())
 
 
 def baseconv(x, hat_inv_m, q_own, qneg_own, W_m, D_mod_m, inv_d, q_gen,

@@ -86,8 +86,9 @@ def test_hemm_program_correct_and_schedules_bit_exact(setup):
 
 
 def test_arena_one_slot_per_unique_operand(setup):
-    """Batched compile over B items with S unique DiagSets allocates S
-    operand slots (and S arena entries) — NOT B."""
+    """Batched compile over B items with S unique DiagSets stores S operand
+    slots — NOT B — in ONE arena entry for the launch, beside the context's
+    one rotation-key table."""
     s = setup
     ctx = HEContext(s["ctx"].eng, s["ctx"].keys)    # fresh arena to count
     plan = s["plan"]
@@ -97,12 +98,21 @@ def test_arena_one_slot_per_unique_operand(setup):
     assert run.plan.batch == 5
     assert run.plan.n_diag_slots == 2
     assert run.plan.diag_slots == (0, 1, 0, 0, 1)
-    assert len(ctx.arena) == 2
+    assert sorted(k[0] for k in ctx.arena._entries) == ["hlt_operands",
+                                                        "key_table"]
+    k0, k1, u_m, perms, is_id, key_idx = run._operands
+    assert k0 is ctx.key_table().k0                  # the table, not a copy
+    assert u_m.shape[0] == perms.shape[0] == key_idx.shape[0] == 2
     assert run.plan.operand_bytes_naive > run.plan.operand_bytes
-    # a second program over the same sets adds NO arena entries
-    compile_hlt(ctx, [plan.ds_tau, plan.ds_sigma], level=s["ctA"].level,
+    # a second program over the same launch adds NO arena entries; one over
+    # another launch adds its own operands and reads the same key table
+    compile_hlt(ctx, [plan.ds_sigma, plan.ds_tau], level=s["ctA"].level,
                 schedule="pallas")
     assert len(ctx.arena) == 2
+    other = compile_hlt(ctx, [plan.ds_tau, plan.ds_sigma],
+                        level=s["ctA"].level, schedule="pallas")
+    assert len(ctx.arena) == 3
+    assert other._operands[0] is k0
     # execution is bit-exact vs singles, with repeated cts deduped too
     items = [s["ctA"], s["ctB"], s["ctA"], s["ctB"], s["ctA"]]
     outs = run(items)

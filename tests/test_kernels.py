@@ -126,20 +126,26 @@ def test_fused_hlt_batched_kernel(logN, B, d, nbeta, chunk):
 @pytest.mark.parametrize("logN,H,S,B,d,nbeta,chunk",
                          [(5, 2, 3, 5, 4, 1, 2), (6, 3, 2, 6, 6, 2, 3)])
 def test_fused_hlt_indexed_kernel(logN, H, S, B, d, nbeta, chunk):
-    """Slot-indexed kernel over deduped operands == batched kernel on the
-    gathered (replicated) operands — the scalar-prefetch index maps must be
-    pure routing, bit for bit."""
-    ctx = _ctx(logN=logN, L=5, k=2, beta=nbeta)
+    """Slot-indexed kernel over deduped operands and one key table == batched
+    kernel on the gathered (replicated) operands and per-diagonal key
+    copies — the scalar-prefetch index maps (slots, key rows, the limb rows
+    and leading digits a lower level reads) must be pure routing, bit for
+    bit."""
+    ctx = _ctx(logN=logN, L=5, k=2, beta=nbeta + 1)
     rng = np.random.default_rng(8)
     p = ctx.params
-    M, N = p.num_total, p.N
+    Mt, N = p.num_total, p.N
+    rows = np.sort(rng.choice(Mt, Mt - 1, replace=False)).astype(np.int32)
+    M = len(rows)
     qs = np.asarray(ctx.moduli_host, dtype=np.uint64)[:, None]
-    digits = _rand(rng, qs[None], (H, nbeta, M, N))
-    c0e = _rand(rng, qs, (H, M, N))
-    c1e = _rand(rng, qs, (H, M, N))
-    u = _rand(rng, qs[None], (S, d, M, N))
-    rk0 = _rand(rng, qs[None, None], (S, d, nbeta, M, N))
-    rk1 = _rand(rng, qs[None, None], (S, d, nbeta, M, N))
+    digits = _rand(rng, qs[None, rows], (H, nbeta, M, N))
+    c0e = _rand(rng, qs[rows], (H, M, N))
+    c1e = _rand(rng, qs[rows], (H, M, N))
+    u = _rand(rng, qs[None, rows], (S, d, M, N))
+    K = 3
+    rk0 = _rand(rng, qs[None, None], (K, nbeta + 1, Mt, N))
+    rk1 = _rand(rng, qs[None, None], (K, nbeta + 1, Mt, N))
+    key_idx = rng.integers(0, K, (S, d)).astype(np.int32)
     perms = np.stack([[np.random.default_rng(10 * s + i).permutation(N)
                        for i in range(d)] for s in range(S)]).astype(np.int32)
     is_id = np.zeros((S, d, 1), np.int32)
@@ -147,17 +153,20 @@ def test_fused_hlt_indexed_kernel(logN, H, S, B, d, nbeta, chunk):
         is_id[s, s % d] = 1
     ct_slots = rng.integers(0, H, B).astype(np.int32)
     diag_slots = rng.integers(0, S, B).astype(np.int32)
+    q32, qneg = ctx.moduli_u32[rows], ctx.qneg_inv[rows]
     got0, got1 = ops.fused_hlt_indexed(
         jnp.asarray(digits), jnp.asarray(c0e), jnp.asarray(c1e),
         jnp.asarray(u), jnp.asarray(rk0), jnp.asarray(rk1),
-        jnp.asarray(perms), jnp.asarray(is_id), jnp.asarray(ct_slots),
-        jnp.asarray(diag_slots), ctx.moduli_u32, ctx.qneg_inv, chunk=chunk)
+        jnp.asarray(key_idx), jnp.asarray(rows), jnp.asarray(perms),
+        jnp.asarray(is_id), jnp.asarray(ct_slots), jnp.asarray(diag_slots),
+        q32, qneg, chunk=chunk)
+    copies = lambda t: t[key_idx][:, :, :nbeta][:, :, :, rows][diag_slots]
     want0, want1 = ops.fused_hlt_batched(
         jnp.asarray(digits[ct_slots]), jnp.asarray(c0e[ct_slots]),
         jnp.asarray(c1e[ct_slots]), jnp.asarray(u[diag_slots]),
-        jnp.asarray(rk0[diag_slots]), jnp.asarray(rk1[diag_slots]),
+        jnp.asarray(copies(rk0)), jnp.asarray(copies(rk1)),
         jnp.asarray(perms[diag_slots]), jnp.asarray(is_id[diag_slots]),
-        ctx.moduli_u32, ctx.qneg_inv, chunk=chunk)
+        q32, qneg, chunk=chunk)
     np.testing.assert_array_equal(np.asarray(got0), np.asarray(want0))
     np.testing.assert_array_equal(np.asarray(got1), np.asarray(want1))
 

@@ -71,7 +71,7 @@ def traced(tmp_path_factory):
     off = prog(ctA, ctB)
     sps = _program_spans(logdir)
     return dict(ctx=ctx, prog=prog, l=l, shape=(m, n), on=on, off=off,
-                spans=sps, parent=_parents(sps))
+                spans=sps, parent=_parents(sps), logdir=logdir)
 
 
 def _children(t, i) -> list:
@@ -111,6 +111,31 @@ def test_set_up_spans_are_top_level(traced):
 def test_each_hlt_step_hoists_launches_and_finishes(traced, stage):
     (i,) = _index(traced, stage)
     assert _children(traced, i) == ["hlt.hoist", "hlt.launch", "hlt.finish"]
+
+
+def test_key_table_is_built_inside_compile(traced):
+    (compile_,) = _index(traced, "hemm.compile")
+    assert _children(traced, compile_) == ["hlt.keytable"]
+
+
+def test_each_launch_carries_its_counters(traced):
+    """Each ``hlt.launch`` event holds its plan's launch counters as
+    metadata: rotation slots, real rotations, padding and the key-table and
+    diagonal bytes it reads."""
+    from jax.profiler import ProfileData
+    path = sorted(pathlib.Path(traced["logdir"]).rglob("*.xplane.pb"))[-1]
+    got = [dict(ev.stats) for plane in ProfileData.from_file(str(path)).planes
+           if plane.name.startswith("/host") for line in plane.lines
+           for ev in line.events if ev.name == "hlt.launch"]
+    prog = traced["prog"]
+    want = [prog.plan.step1.launch_stats(), prog.plan.step2.launch_stats()]
+    assert got == want
+    l = traced["l"]
+    step2 = prog.plan.step2
+    assert want[1]["slots"] == 2 * l * step2.d_pad
+    assert want[1]["padding"] == want[1]["slots"] - sum(step2.d)
+    assert 0 < want[1]["rotations"] <= sum(step2.d)
+    assert want[1]["key_bytes"] > 0 and want[1]["diag_bytes"] > 0
 
 
 def test_tail_holds_l_mults_rescales_and_l_minus_1_adds(traced):

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 import repro  # noqa: F401
 from repro.core.ckks import CkksEngine
+from repro.core import ntt as core_ntt
 from repro.core.costmodel import pick_rotation_chunk
 from repro.core.params import SET_A
 from repro.kernels import basechange, fused_hlt, ntt as kntt
@@ -124,25 +125,61 @@ def test_moddown_fused_compiles(one_chip, eng, level):
         _sds(one_chip, (4, m_ext, P.N)), arrays)
 
 
-# (level, batch B, diagonal sets S, hoisting products H, diagonals d):
-# Step 1 is {σ(A), τ(B)} with 2·64-1 diagonals each; Step 2 is the 2·l = 128
-# ε/ω HLTs with 2 diagonals each off the 2 Step-1 outputs (type IV, l = 64).
-@pytest.mark.parametrize("level,B,S,H,d", [(P.L, 2, 2, 2, 127),
-                                           (P.L - 1, 128, 128, 2, 2)],
-                         ids=["step1", "step2"])
-def test_fused_hlt_indexed_compiles(one_chip, level, B, S, H, d):
+def _compile_fused_hlt(sharding, level, B, S, H, d, K):
+    """The slot-indexed fused HLT of one launch, reading a table of K
+    rotation keys at the top basis, with the diagonals and the table stored
+    as kernel tiles as the context keeps them; returns the compiled
+    executable."""
     nbeta, M = level + 1, level + 1 + P.k
+    tile = core_ntt.tile_shape(P.N)
     chunk = max(1, min(pick_rotation_chunk(P, nbeta=nbeta), d))
     d_pad = -(-d // chunk) * chunk
-    s = lambda shape, dtype=U32: _sds(one_chip, shape, dtype)
+    s = lambda shape, dtype=U32: _sds(sharding, shape, dtype)
     i32 = jnp.int32
-    _compile(lambda *a: fused_hlt.fused_hlt_indexed(
+    return _compile(lambda *a: fused_hlt.fused_hlt_indexed(
         *a, chunk=chunk, interpret=False),
         s((H, nbeta, M, P.N)), s((H, M, P.N)), s((H, M, P.N)),
-        s((S, d_pad, M, P.N)), s((S, d_pad, nbeta, M, P.N)),
-        s((S, d_pad, nbeta, M, P.N)), s((S, d_pad, P.N), i32),
-        s((S, d_pad, 1), i32), s((B,), i32), s((B,), i32),
-        s((M, 1)), s((M, 1)))
+        s((S, d_pad, M) + tile), s((K, P.beta, P.num_total) + tile),
+        s((K, P.beta, P.num_total) + tile), s((S, d_pad), i32), s((M,), i32),
+        s((S, d_pad, P.N), i32), s((S, d_pad, 1), i32), s((B,), i32),
+        s((B,), i32), s((M, 1)), s((M, 1)))
+
+
+# (level, batch B, diagonal sets S, hoisting products H, diagonals d, keys K):
+# Step 1 is {σ(A), τ(B)} with 2·64-1 diagonals each; Step 2 is the 2·l = 128
+# ε/ω HLTs with 2 diagonals each off the 2 Step-1 outputs (type IV, l = 64),
+# reading Step 2's digits and limb rows out of the top-level key table.
+@pytest.mark.parametrize("level,B,S,H,d,K", [(P.L, 2, 2, 2, 127, 252),
+                                             (P.L - 1, 128, 128, 2, 2, 252)],
+                         ids=["step1", "step2"])
+def test_fused_hlt_indexed_compiles(one_chip, level, B, S, H, d, K):
+    _compile_fused_hlt(one_chip, level, B, S, H, d, K)
+
+
+def test_fused_hlt_type_ii_step2_fits(one_chip):
+    """Set-A type II (64×16×64) Step 2: 32 HLTs of up to 194 diagonals
+    (d_pad 195 at the cost model's chunk of 13) against the table of the
+    3,117 distinct rotation keys of its 3,132 rotation steps: arguments and
+    working bytes of the launch fit one chip's HBM."""
+    compiled = _compile_fused_hlt(one_chip, P.L - 1, 32, 32, 2, 194, 3117)
+    mem = compiled.memory_analysis()
+    table = 2 * 3117 * P.beta * P.num_total * P.N * 4
+    assert mem.argument_size_in_bytes > table
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def test_key_table_is_built_in_place(one_chip):
+    """The type II table of 3,117 rotation keys is converted to the
+    Montgomery domain in place: the table is aliased, never copied."""
+    from repro.core import ckks
+    M = P.num_total
+    tab = _sds(one_chip, (3117, P.beta, M) + core_ntt.tile_shape(P.N))
+    col = _sds(one_chip, (M, 1))
+    mem = ckks._table_to_mont.lower(tab, tab, col, col, col).compile(
+    ).memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 3117 * P.beta * M * P.N * 4
+    assert mem.temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("kind", ["modmul", "modadd", "baseconv"])
